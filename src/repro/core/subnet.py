@@ -30,6 +30,7 @@ import numpy as np
 from repro.core.nl_config import NeuraLUTConfig
 
 Params = Dict[str, Any]
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _widths(F: int, L: int, N: int) -> List[int]:
@@ -66,15 +67,23 @@ def subnet_apply(p: Params, x: jax.Array, S: int, *,
     bit-identical; which layout (or Pallas kernel) runs where is decided
     by ``core.exec_plan.SubnetExec`` — conversion and eval stay on the
     canonical (B, O, n) einsum the tables are defined against.
+
+    The einsums run at ``HIGHEST`` precision: the hidden function is
+    float32 by definition, and a TPU's default matmul precision rounds
+    f32 operands to bfloat16, which would part the eval forward from
+    the kernel routes (float32 on the vector unit) by far more than
+    rounding order.
     """
     if batch_leading:
         def mm(h, w, b):
-            return jnp.einsum("obi,oij->obj", h, w) + b[:, None, :]
+            return jnp.einsum("obi,oij->obj", h, w,
+                              precision=HIGHEST) + b[:, None, :]
 
         h = x.transpose(1, 0, 2)  # (O, B, F)
     else:
         def mm(h, w, b):
-            return jnp.einsum("boi,oij->boj", h, w) + b[None]
+            return jnp.einsum("boi,oij->boj", h, w,
+                              precision=HIGHEST) + b[None]
 
         h = x
 

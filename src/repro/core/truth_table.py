@@ -134,30 +134,12 @@ def _get_sweep(cfg: NeuraLUTConfig, layer_idx: int, chunk: int,
     return fn
 
 
-def _jit_cache_size(fn) -> int:
-    """Compiled-executable count of a ``jax.jit`` wrapper, across jax
-    versions.  ``_cache_size`` is a private accessor whose name has moved
-    before (``_cache_size()`` today, ``_cache_size`` attribute /
-    ``cache_size`` elsewhere); fall back through the known spellings and
-    report -1 (unknown) rather than crash on a jax upgrade."""
-    for name in ("_cache_size", "cache_size"):
-        attr = getattr(fn, name, None)
-        if attr is None:
-            continue
-        try:
-            return int(attr() if callable(attr) else attr)
-        except Exception:
-            continue
-    return -1
-
-
 def convert_cache_stats() -> Dict[Tuple, int]:
     """{static sweep key: number of compiled executables} — one entry per
     distinct layer geometry seen this process, one compile per distinct
-    operand-shape signature under it (-1 when the running jax exposes no
-    cache-size accessor).  Tests assert consecutive layers sharing a
-    geometry reuse a single compile."""
-    return {k: _jit_cache_size(fn) for k, fn in _SWEEP_CACHE.items()}
+    operand-shape signature under it.  Tests assert consecutive layers
+    sharing a geometry reuse a single compile."""
+    return {k: fn._cache_size() for k, fn in _SWEEP_CACHE.items()}
 
 
 def clear_convert_cache() -> None:

@@ -1,47 +1,50 @@
 """Pallas TPU kernel: fused fwd+bwd grouped sub-network *training* step.
 
 `kernels/neuralut_mlp.py` fuses the grouped-subnet **inference** pass in
-VMEM; this module is its training twin.  PR 4 profiling showed the
-per-layer dW/dx einsums of the grouped subnet dominate ~60% of a JSC-5L
-training step even in the neuron-leading layout — each of the L
-sub-layers round-trips its (B, O, N) activations and cotangents through
-HBM twice (fwd + bwd).  Here one forward launch evaluates all L
-sub-layers (+ skip chunks) for a (Bt, Ot) tile entirely in VMEM and
-*saves the per-layer activations* as it goes; one backward launch
-reloads those activations and produces dW/db/dx for every sub-layer in
-the same neuron-leading layout, accumulating the weight gradients
-across batch tiles inside the kernel grid (the B tile is the innermost,
-fastest-moving grid dim, so each (O-tile) dW block stays resident while
-its batch partials accumulate).
+VMEM; this module is its training twin.  The per-layer dW/dx einsums of
+the grouped subnet dominate a JSC-5L training step on the jnp routes —
+each of the L sub-layers round-trips its (B, O, N) activations and
+cotangents through HBM twice (fwd + bwd).  Here one forward launch
+evaluates all L sub-layers (+ skip chunks) for a (Bt, Ot) tile entirely
+in VMEM and *saves the per-layer activations* as it goes; one backward
+launch reloads those activations and produces dW/db/dx for every
+sub-layer, accumulating the weight gradients across batch tiles inside
+the kernel grid (the B tile is the innermost, fastest-moving grid dim,
+so each O-tile's gradient block stays resident while its batch partials
+accumulate).
 
 The pair is wired up as a ``jax.custom_vjp`` op (``subnet_train_op``):
-the forward primal is bit-comparable to the inference kernel, and the
-backward matches ``jax.grad`` of the jnp einsum path (the gradient
-oracle, tests/test_train_kernel.py) to float32 tolerance — the only
-divergence is f32 summation order.
+the forward primal runs the inference kernel's body, and the backward
+matches ``jax.grad`` of the jnp einsum path (the gradient oracle,
+tests/test_train_kernel.py) to float32 tolerance — the only divergence
+is f32 summation order.
 
-Saved residuals: the input to every sub-layer ``i >= 1`` (the
-post-ReLU activation ``a_i``; layer 0's input is the gathered ``xg``
-which the caller already holds).  ReLU masks are recovered from the
-post-activation sign (``a > 0`` ⇔ pre-activation ``> 0``, matching
+Both launches use the inference kernel's lane-dense layout (neurons on
+lanes, batch on sublanes, an (n, Bt, Ot) block per activation — see
+kernels/neuralut_mlp.py).  Saved residuals: the input to every
+sub-layer ``i >= 1`` (the post-ReLU activation ``a_i``, (n_i, B, O));
+layer 0's input is the gathered ``xg``.  ReLU masks are recovered from
+the post-activation sign (``a > 0`` ⇔ pre-activation ``> 0``, matching
 ``jax.nn.relu``'s zero subgradient at 0), so no pre-activation copies
 are stored.
 
-Weight layout matches kernels/neuralut_mlp.py: layer i has w
-(O, n_i, n_{i+1}), b (O, n_{i+1}); skip chunk c has r (O, n_{cS},
+The caller-facing layout is the canonical one: xg (B, O, F), layer i
+w (O, n_i, n_{i+1}), b (O, n_{i+1}); skip chunk c has r (O, n_{cS},
 n_{(c+1)S}).  The last layer has n_out == 1; the primal output is
 (B, O).
 """
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Sequence, Tuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.neuralut_mlp import auto_blocks
+from repro.kernels.neuralut_mlp import (auto_blocks, canonical_weight,
+                                        forward_body, kernel_operands,
+                                        param_spec)
 
 
 class GradMeta(NamedTuple):
@@ -61,28 +64,8 @@ def _interp(meta: GradMeta) -> bool:
     return meta.interpret
 
 
-def _mm(h, w, b=None):
-    """(Bt, Ot, ni) x (Ot, ni, no) -> (Bt, Ot, no), neuron-batched."""
-    out = jax.lax.dot_general(
-        h, w, dimension_numbers=(((2,), (1,)), ((1,), (0,))),
-        preferred_element_type=jnp.float32).transpose(1, 0, 2)
-    return out if b is None else out + b[None]
-
-
-def _mm_t(g, w):
-    """Cotangent through the matmul: (Bt, Ot, no) x (Ot, ni, no) ->
-    (Bt, Ot, ni)."""
-    return jax.lax.dot_general(
-        g, w, dimension_numbers=(((2,), (2,)), ((1,), (0,))),
-        preferred_element_type=jnp.float32).transpose(1, 0, 2)
-
-
-def _dw(a, g):
-    """Per-neuron weight grad partial for one batch tile:
-    (Bt, Ot, ni) x (Bt, Ot, no) -> (Ot, ni, no)."""
-    return jax.lax.dot_general(
-        a, g, dimension_numbers=(((0,), (0,)), ((1,), (1,))),
-        preferred_element_type=jnp.float32)
+def _act_spec(n: int, bb: int, bo: int) -> pl.BlockSpec:
+    return pl.BlockSpec((n, bb, bo), lambda j, i: (0, i, j))
 
 
 # ---------------------------------------------------------------------------
@@ -90,148 +73,95 @@ def _dw(a, g):
 
 
 def _fwd_kernel(nlayers: int, skip: int, *refs):
-    """refs: xg, w_0, b_0..w_{L-1}, b_{L-1} [, r_0, rb_0, ...],
+    """refs: x, w_0, b_0..w_{L-1}, b_{L-1} [, r_0, rb_0, ...],
     out, act_1..act_{L-1}."""
-    xg_ref = refs[0]
-    ws = [(refs[1 + 2 * i], refs[2 + 2 * i]) for i in range(nlayers)]
-    base = 1 + 2 * nlayers
-    nch = (nlayers // skip) if skip else 0
-    rs = [(refs[base + 2 * c], refs[base + 2 * c + 1]) for c in range(nch)]
-    out_ref = refs[base + 2 * nch]
-    act_refs = refs[base + 2 * nch + 1:]
+    nparams = 2 * nlayers + (2 * (nlayers // skip) if skip else 0)
+    out_ref = refs[1 + nparams]
+    act_refs = refs[2 + nparams:]
 
     def save(i, h):  # input to sub-layer i (i >= 1)
         act_refs[i - 1][...] = h
 
-    x = xg_ref[...].astype(jnp.float32)
-    if skip == 0:
-        h = x
-        for i, (w, b) in enumerate(ws):
-            if i > 0:
-                save(i, h)
-            h = _mm(h, w[...], b[...])
-            if i < nlayers - 1:
-                h = jnp.maximum(h, 0.0)
-    else:
-        h = x
-        for c in range(nch):
-            if c > 0:
-                save(c * skip, h)
-            res = _mm(h, rs[c][0][...], rs[c][1][...])
-            hh = h
-            for j in range(skip):
-                i = c * skip + j
-                if j > 0:
-                    save(i, hh)
-                w, b = ws[i]
-                hh = _mm(hh, w[...], b[...])
-                if j < skip - 1:
-                    hh = jnp.maximum(hh, 0.0)
-            h = hh + res
-            if c < nch - 1:
-                h = jnp.maximum(h, 0.0)
-    out_ref[...] = h[..., 0]
-
-
-def _widths(f: int, layer_ws: Sequence) -> Tuple[int, ...]:
-    return (f,) + tuple(w.shape[2] for w in layer_ws)
-
-
-def _w_spec(block_o: int, w) -> pl.BlockSpec:
-    return pl.BlockSpec((block_o,) + w.shape[1:], lambda j, i: (j, 0, 0))
-
-
-def _b_spec(block_o: int, b) -> pl.BlockSpec:
-    return pl.BlockSpec((block_o, b.shape[1]), lambda j, i: (j, 0))
+    out_ref[...] = forward_body(refs[0][...].astype(jnp.float32),
+                                refs[1:1 + nparams], nlayers, skip, save)
 
 
 def _forward(meta: GradMeta, xg, layer_ws, layer_bs, skip_ws, skip_bs):
+    """-> (out (B, O), residuals in kernel layout)."""
     b, o, f = xg.shape
     bb, bo = meta.block_b, meta.block_o
     if b % bb or o % bo:
         raise ValueError(f"(B={b}, O={o}) not divisible by ({bb}, {bo})")
-    grid = (o // bo, b // bb)  # B tiles innermost (matches backward)
-    w = _widths(f, layer_ws)
-
-    in_specs = [pl.BlockSpec((bb, bo, f), lambda j, i: (i, j, 0))]
-    args = [xg]
-    for lw, lb in zip(layer_ws, layer_bs):
-        in_specs += [_w_spec(bo, lw), _b_spec(bo, lb)]
-        args += [lw, lb]
-    for sw, sb in zip(skip_ws, skip_bs):
-        in_specs += [_w_spec(bo, sw), _b_spec(bo, sb)]
-        args += [sw, sb]
+    x, params = kernel_operands(xg, layer_ws, layer_bs, skip_ws, skip_bs)
+    widths = [w.shape[1] for w in layer_ws]  # inputs of sub-layers
 
     out_shapes = [jax.ShapeDtypeStruct((b, o), jnp.float32)]
     out_specs = [pl.BlockSpec((bb, bo), lambda j, i: (i, j))]
-    for i in range(1, meta.nlayers):
-        out_shapes.append(jax.ShapeDtypeStruct((b, o, w[i]), jnp.float32))
-        out_specs.append(
-            pl.BlockSpec((bb, bo, w[i]), lambda j, i: (i, j, 0)))
+    for n in widths[1:]:
+        out_shapes.append(jax.ShapeDtypeStruct((n, b, o), jnp.float32))
+        out_specs.append(_act_spec(n, bb, bo))
 
     outs = pl.pallas_call(
         functools.partial(_fwd_kernel, meta.nlayers, meta.skip),
-        grid=grid,
-        in_specs=in_specs,
+        grid=(o // bo, b // bb),  # B tiles innermost (matches backward)
+        in_specs=[_act_spec(f, bb, bo)] + [param_spec(p, bo)
+                                           for p in params],
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=_interp(meta),
-    )(*args)
-    return outs[0], tuple(outs[1:])
+    )(x, *params)
+    return outs[0], (x, tuple(outs[1:]), tuple(params[0::2]))
 
 
 # ---------------------------------------------------------------------------
 # backward: dx, dW, db for every sub-layer and skip chunk in one launch
 
 
-def _acc(ref, part):
-    """Accumulate across B tiles: the B grid dim is innermost, so each
-    (O-tile) gradient block is revisited consecutively — init on the
-    first tile, add on the rest (the standard Pallas reduction
-    pattern)."""
-    i = pl.program_id(1)
+def _dense_bwd(a, g, w_ref, dw_ref, db_ref):
+    """Through one grouped layer z[k] = sum_i a[i] w[i, k] + b[k]: add
+    this batch tile's dW/db partials into the resident gradient blocks
+    and return the (n_i, Bt, Ot) cotangent wrt the layer input ``a``.
 
-    @pl.when(i == 0)
-    def _():
-        ref[...] = part
-
-    @pl.when(i > 0)
-    def _():
-        ref[...] = ref[...] + part
+    a: (n_i, Bt, Ot); g: (n_o, Bt, Ot) cotangent of z."""
+    w = w_ref[...]
+    db_ref[...] += jnp.sum(g, axis=1, keepdims=True)
+    dw_ref[...] += jnp.stack([jnp.sum(a[i:i + 1] * g, axis=1, keepdims=True)
+                              for i in range(a.shape[0])])
+    return jnp.stack([jnp.sum(w[i] * g, axis=0)
+                      for i in range(a.shape[0])])
 
 
 def _bwd_kernel(nlayers: int, skip: int, *refs):
-    """refs: g, xg, act_1..act_{L-1}, w_0..w_{L-1} [, r_0..],
+    """refs: g, x, act_1..act_{L-1}, w_0..w_{L-1} [, r_0..],
     dx, dw_0, db_0, .., dw_{L-1}, db_{L-1} [, dr_0, drb_0, ..]."""
-    g_ref, xg_ref = refs[0], refs[1]
-    acts = refs[2:2 + nlayers - 1]
-    base = 2 + nlayers - 1
-    ws = refs[base:base + nlayers]
-    base += nlayers
     nch = (nlayers // skip) if skip else 0
-    rs = refs[base:base + nch]
-    base += nch
+    g_ref, x_ref = refs[0], refs[1]
+    acts = refs[2:1 + nlayers]
+    base = 1 + nlayers
+    ws = refs[base:base + nlayers]
+    rs = refs[base + nlayers:base + nlayers + nch]
+    base += nlayers + nch
     dx_ref = refs[base]
-    dws = [(refs[base + 1 + 2 * i], refs[base + 2 + 2 * i])
-           for i in range(nlayers)]
-    drs = [(refs[base + 1 + 2 * nlayers + 2 * c],
-            refs[base + 2 + 2 * nlayers + 2 * c]) for c in range(nch)]
+    grads = refs[base + 1:]
+    dws = [(grads[2 * i], grads[2 * i + 1]) for i in range(nlayers)]
+    drs = [(grads[2 * (nlayers + c)], grads[2 * (nlayers + c) + 1])
+           for c in range(nch)]
 
-    x = xg_ref[...].astype(jnp.float32)
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        for ref in grads:
+            ref[...] = jnp.zeros(ref.shape, ref.dtype)
 
-    def a_in(i):  # input to sub-layer i (saved activation, or xg)
+    x = x_ref[...].astype(jnp.float32)
+
+    def a_in(i):  # input to sub-layer i (saved activation, or x)
         return x if i == 0 else acts[i - 1][...]
 
-    gh = g_ref[...].astype(jnp.float32)[..., None]  # (Bt, Ot, 1)
-
     def through_layer(i, gm):
-        """dW_i/db_i partials from this tile; returns cotangent wrt the
-        layer's input (pre-ReLU-mask)."""
         a = a_in(i)
-        _acc(dws[i][0], _dw(a, gm))
-        _acc(dws[i][1], jnp.sum(gm, axis=0))
-        return _mm_t(gm, ws[i][...]), a
+        return _dense_bwd(a, gm, ws[i], *dws[i]), a
 
+    gh = g_ref[...][None]
     if skip == 0:
         gm = gh
         for i in range(nlayers - 1, -1, -1):
@@ -239,76 +169,50 @@ def _bwd_kernel(nlayers: int, skip: int, *refs):
             if i > 0:
                 gm = gm * (a > 0.0)
         dx_ref[...] = gm
-    else:
-        gout = gh
-        for c in range(nch - 1, -1, -1):
-            hc = a_in(c * skip)
-            _acc(drs[c][0], _dw(hc, gout))
-            _acc(drs[c][1], jnp.sum(gout, axis=0))
-            ghc = _mm_t(gout, rs[c][...])
-            gm = gout
-            for i in range((c + 1) * skip - 1, c * skip - 1, -1):
-                gm, a = through_layer(i, gm)
-                if i > c * skip:
-                    gm = gm * (a > 0.0)
-            ghc = ghc + gm
-            if c > 0:
-                gout = ghc * (hc > 0.0)  # inter-chunk ReLU boundary
-            else:
-                dx_ref[...] = ghc
+        return
+    gout = gh
+    for c in range(nch - 1, -1, -1):
+        hc = a_in(c * skip)
+        ghc = _dense_bwd(hc, gout, rs[c], *drs[c])
+        gm = gout
+        for i in range((c + 1) * skip - 1, c * skip - 1, -1):
+            gm, a = through_layer(i, gm)
+            if i > c * skip:
+                gm = gm * (a > 0.0)
+        ghc = ghc + gm
+        if c > 0:
+            gout = ghc * (hc > 0.0)  # inter-chunk ReLU boundary
+        else:
+            dx_ref[...] = ghc
 
 
-def _backward(meta: GradMeta, g, xg, acts, layer_ws, skip_ws):
-    b, o, f = xg.shape
+def _backward(meta: GradMeta, g, res):
+    x, acts, wks = res
+    f, b, o = x.shape
     bb, bo = meta.block_b, meta.block_o
-    grid = (o // bo, b // bb)
-    nch = (meta.nlayers // meta.skip) if meta.skip else 0
-
-    in_specs = [pl.BlockSpec((bb, bo), lambda j, i: (i, j)),
-                pl.BlockSpec((bb, bo, f), lambda j, i: (i, j, 0))]
-    args = [g, xg]
-    for a in acts:
-        in_specs.append(
-            pl.BlockSpec((bb, bo, a.shape[2]), lambda j, i: (i, j, 0)))
-        args.append(a)
-    for lw in layer_ws:
-        in_specs.append(_w_spec(bo, lw))
-        args.append(lw)
-    for sw in skip_ws:
-        in_specs.append(_w_spec(bo, sw))
-        args.append(sw)
-
-    out_shapes = [jax.ShapeDtypeStruct((b, o, f), jnp.float32)]
-    out_specs = [pl.BlockSpec((bb, bo, f), lambda j, i: (i, j, 0))]
-
-    def grad_outs(w_list):
-        for lw in w_list:
-            out_shapes.append(
-                jax.ShapeDtypeStruct(lw.shape, jnp.float32))
-            out_specs.append(_w_spec(bo, lw))
-            out_shapes.append(
-                jax.ShapeDtypeStruct(lw.shape[::2], jnp.float32))
-            out_specs.append(pl.BlockSpec((bo, lw.shape[2]),
-                                          lambda j, i: (j, 0)))
-
-    grad_outs(layer_ws)
-    grad_outs(skip_ws)
+    nl = meta.nlayers
+    grad_shapes = []
+    for wk in wks:  # dw (n_i, n_o, 1, O), db (n_o, 1, O)
+        grad_shapes += [wk.shape, wk.shape[1:]]
 
     outs = pl.pallas_call(
-        functools.partial(_bwd_kernel, meta.nlayers, meta.skip),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shapes,
+        functools.partial(_bwd_kernel, nl, meta.skip),
+        grid=(o // bo, b // bb),
+        in_specs=([pl.BlockSpec((bb, bo), lambda j, i: (i, j)),
+                   _act_spec(f, bb, bo)]
+                  + [_act_spec(a.shape[0], bb, bo) for a in acts]
+                  + [param_spec(wk, bo) for wk in wks]),
+        out_specs=[_act_spec(f, bb, bo)] + [
+            param_spec(jax.ShapeDtypeStruct(s, jnp.float32), bo)
+            for s in grad_shapes],
+        out_shape=[jax.ShapeDtypeStruct(s, jnp.float32)
+                   for s in [x.shape] + grad_shapes],
         interpret=_interp(meta),
-    )(*args)
-    dx = outs[0]
-    dlw = tuple(outs[1 + 2 * i] for i in range(meta.nlayers))
-    dlb = tuple(outs[2 + 2 * i] for i in range(meta.nlayers))
-    off = 1 + 2 * meta.nlayers
-    dsw = tuple(outs[off + 2 * c] for c in range(nch))
-    dsb = tuple(outs[off + 1 + 2 * c] for c in range(nch))
-    return dx, dlw, dlb, dsw, dsb
+    )(g, x, *acts, *wks)
+    dws = tuple(canonical_weight(d) for d in outs[1::2])
+    dbs = tuple(d[:, 0, :].T for d in outs[2::2])
+    return outs[0].transpose(1, 2, 0), dws[:nl], dbs[:nl], dws[nl:], \
+        dbs[nl:]
 
 
 # ---------------------------------------------------------------------------
@@ -330,26 +234,20 @@ def subnet_train_op(meta: GradMeta, xg, layer_ws, layer_bs,
 
 
 def _train_fwd(meta, xg, layer_ws, layer_bs, skip_ws, skip_bs):
-    out, acts = _forward(meta, xg, layer_ws, layer_bs, skip_ws, skip_bs)
-    return out, (xg, acts, layer_ws, skip_ws)
+    return _forward(meta, xg, layer_ws, layer_bs, skip_ws, skip_bs)
 
 
 def _train_bwd(meta, res, g):
-    xg, acts, layer_ws, skip_ws = res
-    dx, dlw, dlb, dsw, dsb = _backward(meta, g, xg, acts, layer_ws,
-                                       skip_ws)
-    return dx, dlw, dlb, dsw, dsb
+    return _backward(meta, g, res)
 
 
 subnet_train_op.defvjp(_train_fwd, _train_bwd)
 
 
 def subnet_train_meta(b: int, o: int, nlayers: int, skip: int, *,
-                      block_b: Optional[int] = None,
-                      block_o: Optional[int] = None,
                       interpret: Optional[bool] = None) -> GradMeta:
-    """GradMeta with legal auto-shaped tiles for a (B, O, F) operand."""
-    auto_b, auto_o = auto_blocks(b, o)
-    return GradMeta(nlayers=nlayers, skip=skip,
-                    block_b=block_b or auto_b, block_o=block_o or auto_o,
+    """GradMeta with the tiles :func:`neuralut_mlp.auto_blocks` derives
+    from the (B, O) shape."""
+    bb, bo = auto_blocks(b, o)
+    return GradMeta(nlayers=nlayers, skip=skip, block_b=bb, block_o=bo,
                     interpret=interpret)

@@ -26,14 +26,14 @@ from .neuralut_mlp import grouped_subnet
 @functools.partial(jax.jit, static_argnames=("skip", "block_b", "block_o",
                                              "interpret"))
 def grouped_subnet_op(xg, layer_ws, layer_bs, skip_ws=None, skip_bs=None, *,
-                      skip: int = 0, block_b: int = 128, block_o: int = 16,
+                      skip: int = 0, block_b: Optional[int] = None,
+                      block_o: Optional[int] = None,
                       interpret: Optional[bool] = None):
-    interp = (not kernel_compiled()) if interpret is None else interpret
     return grouped_subnet(xg, list(layer_ws), list(layer_bs),
                           list(skip_ws) if skip_ws else None,
                           list(skip_bs) if skip_bs else None,
                           skip=skip, block_b=block_b, block_o=block_o,
-                          interpret=interp)
+                          interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "block_o",
@@ -118,21 +118,16 @@ def cascade_apply(codes, shift_mats, packed_tables, *, plan=None,
 def subnet_kernel_apply(fn_params: Dict, xg, skip: int, *,
                         interpret: Optional[bool] = None):
     """Run a whole (B, O, F) grouped sub-network through the fused
-    Pallas kernel (``neuralut_mlp.grouped_subnet``), shaping legal block
-    sizes automatically.  The converter's TPU fast path: one kernel
-    launch evaluates all O neurons' hidden MLPs for a chunk of
-    enumerated codes.  The jnp ``subnet.subnet_apply`` path is the
-    bit-exactness oracle (tests/test_convert_fused.py).
+    Pallas kernel (``neuralut_mlp.grouped_subnet``), its tiles derived
+    from the shape.  The converter's TPU fast path: one kernel launch
+    evaluates all O neurons' hidden MLPs for a chunk of enumerated
+    codes.  The jnp ``subnet.subnet_apply`` path is the bit-exactness
+    oracle (tests/test_convert_fused.py).
     """
-    from .neuralut_mlp import auto_blocks, grouped_subnet
-    b, o, _ = xg.shape
-    block_b, block_o = auto_blocks(b, o)
     kw = subnet_params_to_kernel(fn_params)
-    interp = (not kernel_compiled()) if interpret is None else interpret
     return grouped_subnet(xg, kw["layer_ws"], kw["layer_bs"],
                           kw["skip_ws"], kw["skip_bs"], skip=skip,
-                          block_b=block_b, block_o=block_o,
-                          interpret=interp)
+                          interpret=interpret)
 
 
 def subnet_train_apply(fn_params: Dict, xg, skip: int, *,

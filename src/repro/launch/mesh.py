@@ -9,14 +9,25 @@ axes map to the 2D ICI torus, the pod axis to DCI).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.config import MeshConfig, MULTI_POD, SINGLE_POD
+
+
+def _auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes.  The code places arrays with
+    NamedShardings and lets GSPMD propagate the rest; JAX's make_mesh
+    now defaults to Explicit axes, under which an ambiguous gather or a
+    jit outside ``jax.set_mesh`` is an error instead."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
@@ -24,13 +35,13 @@ def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
 
 
 def make_mesh_from_config(mcfg: MeshConfig):
-    return jax.make_mesh(tuple(mcfg.shape), tuple(mcfg.axes))
+    return _auto_mesh(mcfg.shape, mcfg.axes)
 
 
-def make_host_mesh(shape=(2, 4), axes=("data", "model")):
-    """Small mesh over however many (fake) host devices exist — used by
-    multi-device tests."""
-    return jax.make_mesh(shape, axes)
+def make_host_mesh(shape=(2, 4), axes=("data", "model"), devices=None):
+    """Small mesh over however many (fake) host devices exist, or over
+    ``devices`` — used by multi-device tests."""
+    return _auto_mesh(shape, axes, devices)
 
 
 def make_sweep_mesh(num_devices=None):

@@ -255,6 +255,8 @@ def main() -> None:
                     help="with --tenants: hot-swap tenant 0 onto a "
                          "re-packed redeploy under live traffic")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     if args.mode == "lut" and args.tenants:
         serve_tenants(args)
     elif args.mode == "lut":

@@ -43,6 +43,8 @@ def main() -> None:
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     from repro.data import device_dataset, mnist_pooled
     from repro.launch.mesh import make_sweep_mesh
     from repro.runtime.straggler import StepWatchdog

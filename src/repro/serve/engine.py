@@ -159,6 +159,7 @@ def make_forward_fn(bundle: ServeBundle, *,
         return a if device is None else jax.device_put(a, device)
 
     params = jax.tree.map(put, bundle.serve_params())
+    operands = [params]
 
     if plan.fused:
         # Fused paths only touch the packed tables + shift matrices —
@@ -167,6 +168,7 @@ def make_forward_fn(bundle: ServeBundle, *,
         bundle.prepack()
         packed = [put(t) for t in bundle.packed_tables]
         shift_mats = [put(m) for m in bundle.shift_mats]
+        operands += [packed, shift_mats]
         from repro.kernels.ops import cascade_apply
     else:
         # Per-layer dispatch: plan construction already refused
@@ -177,6 +179,7 @@ def make_forward_fn(bundle: ServeBundle, *,
                                  else t).astype(np.int32))
                   for t in bundle.tables]
         conns = [put(node_static_conns(s)[0]) for s in bundle.statics]
+        operands += [tables, conns]
         in_bits = tuple(cfg.layer_in_bits(i)
                         for i in range(cfg.num_layers))
         if plan.use_kernel:
@@ -205,7 +208,9 @@ def make_forward_fn(bundle: ServeBundle, *,
         vals = LI.class_values(cfg, params, c)
         return jnp.argmax(vals, axis=-1).astype(jnp.int32)
 
-    return jax.jit(forward)
+    jitted = jax.jit(forward)
+    jitted.operands = operands  # the device-resident bundle it closes over
+    return jitted
 
 
 def make_degradable_forward_fn(bundle: ServeBundle, *, plan: CascadeExec,
@@ -222,7 +227,9 @@ def make_degradable_forward_fn(bundle: ServeBundle, *, plan: CascadeExec,
     never sees the kernel error.  The fallback jit is built lazily — a
     healthy engine pays nothing for carrying it.  ``chaos`` checks the
     ``serve.kernel`` site before each primary call (deterministic
-    downgrade tests)."""
+    downgrade tests).  The primary is exposed as ``forward.primary``
+    so that warmup can compile it outside the catch: a route that cannot
+    compile must fail warmup, not be served by the fallback."""
     primary = make_forward_fn(bundle, plan=plan, device=device)
     state: dict = {"fallback": None}
 
@@ -242,6 +249,7 @@ def make_degradable_forward_fn(bundle: ServeBundle, *, plan: CascadeExec,
                     metrics.record_downgrade()
         return fb(x)
 
+    forward.primary = primary
     return forward
 
 
@@ -392,9 +400,19 @@ class _ReplicaExecutor:
                 _complete(r.future, exc=err)
 
     def warmup(self, in_features: int) -> None:
+        """Compile every bucket.  A degradable forward compiles its
+        primary route here, outside its downgrade catch, so a route
+        that cannot compile raises instead of counting a downgrade."""
+        fwd = getattr(self._forward, "primary", self._forward)
         for b in self._buckets:
             x = np.zeros((b, in_features), np.float32)
-            self._forward(self._put(x)).block_until_ready()
+            fwd(self._put(x)).block_until_ready()
+
+    def operand_devices(self) -> set:
+        """Devices holding this replica's resident bundle operands."""
+        fwd = getattr(self._forward, "primary", self._forward)
+        return {d for a in jax.tree.leaves(getattr(fwd, "operands", []))
+                for d in a.devices()}
 
     def _put(self, x: np.ndarray) -> jax.Array:
         """One host->device transfer, straight to the pinned device (a
@@ -620,10 +638,16 @@ class LUTServeEngine:
 
     def warmup(self) -> None:
         """Trace/compile every bucket shape on every replica up front so
-        no client request ever pays a compile."""
+        no client request ever pays a compile.  Raises if the planned
+        route cannot compile (it is never silently downgraded here)."""
         f = self.bundle.cfg.in_features
         for ex in self._executors:
             ex.warmup(f)
+
+    def replica_devices(self) -> List[set]:
+        """Per replica, the devices its bundle operands live on (empty
+        for the sharded executor, whose forward does not record them)."""
+        return [ex.operand_devices() for ex in self._executors]
 
     def close(self) -> None:
         with self._submit_lock:

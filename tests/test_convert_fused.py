@@ -118,35 +118,25 @@ def test_sweep_compile_count_shared_across_layers():
 
 
 def test_jit_cache_size_version_safe():
-    """``convert_cache_stats`` reaches into jit internals; the accessor
-    is private and has moved across jax versions.  The wrapper must
-    survive every spelling — and report -1, not crash, when none
-    exists (a jax upgrade must degrade the *stat*, not the converter)."""
-    class Modern:
-        def _cache_size(self):
-            return 3
-
-    class Attr:
-        cache_size = 5
-
-    class Renamed:
-        def cache_size(self):
-            return 7
-
-    class Broken:
-        def _cache_size(self):
-            raise AttributeError("tracing internals moved")
-
-    assert TT._jit_cache_size(Modern()) == 3
-    assert TT._jit_cache_size(Attr()) == 5
-    assert TT._jit_cache_size(Renamed()) == 7
-    assert TT._jit_cache_size(Broken()) == -1
-    assert TT._jit_cache_size(object()) == -1
-    # and the real jit wrapper still reports a usable count today
-    import jax
+    """``convert_cache_stats`` reads each cached sweep's compile count
+    through the jit wrapper's ``_cache_size()``: it must count real
+    compiles, not report a placeholder."""
     fn = jax.jit(lambda x: x + 1)
+    assert fn._cache_size() == 0
     fn(1)
-    assert TT._jit_cache_size(fn) >= 1
+    fn(2)
+    assert fn._cache_size() == 1
+    fn(jnp.ones(3))
+    assert fn._cache_size() == 2
+    TT.clear_convert_cache()
+    cfg = NeuraLUTConfig(name="tt-stats", in_features=4,
+                         layer_widths=(4, 2), num_classes=2, beta=2,
+                         fan_in=2, kind="subnet", depth=2, width=4,
+                         skip=0)
+    statics, params, state = _trained_like(cfg)
+    TT.convert(cfg, params, state, statics)
+    stats = TT.convert_cache_stats()
+    assert stats and all(n >= 1 for n in stats.values()), stats
 
 
 # ---------------------------------------------------------------------------

@@ -25,6 +25,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_host_mesh
 """
 
 
@@ -41,7 +42,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
 
         cfg = get_config("llama3-8b", reduced=True)
         mcfg = MeshConfig((2, 4), ("data", "model"))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4), ("data", "model"))
         shape = ShapeConfig("t", "train", 64, 4)
         params = api.init_params(cfg, jax.random.PRNGKey(0))
         batch = api.make_batch(cfg, shape, jax.random.PRNGKey(1))
@@ -81,7 +82,7 @@ def test_psum_int8_collective():
         from jax.experimental.shard_map import shard_map
         from repro.optim.grad_compress import psum_int8
 
-        mesh = jax.make_mesh((8,), ("dp",))
+        mesh = make_host_mesh((8,), ("dp",))
         g = jnp.asarray(np.random.default_rng(0).normal(0, 1, (8, 32)),
                         jnp.float32)
 
@@ -115,7 +116,7 @@ def test_elastic_resume_smaller_mesh(tmp_path):
                             params)
 
         big = MeshConfig((2, 4), ("data", "model"))
-        mesh_big = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_big = make_host_mesh((2, 4), ("data", "model"))
         pshard = named(mesh_big, param_partition(cfg, spec, big))
         pp = jax.tree.map(jax.device_put, params, pshard)
 
@@ -124,8 +125,8 @@ def test_elastic_resume_smaller_mesh(tmp_path):
 
         # "pod failure": resume on half the devices
         small = MeshConfig((1, 4), ("data", "model"))
-        mesh_small = jax.sharding.Mesh(
-            np.array(jax.devices()[:4]).reshape(1, 4), ("data", "model"))
+        mesh_small = make_host_mesh((1, 4), ("data", "model"),
+                                    devices=jax.devices()[:4])
         sshard = named(mesh_small, param_partition(cfg, spec, small))
         step, restored = store.restore(params, shardings=sshard)
         assert step == 3
@@ -152,7 +153,7 @@ def test_mini_dryrun_multi_pod_axes():
 
         cfg = get_config("qwen2-moe-a2.7b", reduced=True)
         mcfg = MeshConfig((2, 2, 2), ("pod", "data", "model"))
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_host_mesh((2, 2, 2), ("pod", "data", "model"))
         shape = ShapeConfig("t", "train", 32, 4)
         spec = api.param_spec(cfg, model_axis=2)
         pshard = named(mesh, param_partition(cfg, spec, mcfg))

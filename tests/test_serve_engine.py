@@ -180,6 +180,36 @@ def test_close_resolves_every_inflight_future():
             assert "engine closed" in str(f.exception())
 
 
+def test_warmup_raises_when_primary_route_cannot_compile():
+    """A fused kernel that the backend refuses to compile (here: a
+    Pallas kernel for another platform, compiled rather than
+    interpreted) fails warmup loudly; warmup never hands the batch to
+    the jnp fallback, so no downgrade is counted."""
+    from repro.core.exec_plan import plan_cascade_exec
+    bundle, _ = _tiny_bundle()
+    foreign = ("fused_kernel_gpu" if jax.default_backend() == "tpu"
+               else "fused_kernel_tpu")
+    plan = plan_cascade_exec(bundle.cfg, route=foreign, interpret=False)
+    eng = LUTServeEngine(bundle, plan=plan)
+    try:
+        with pytest.raises(Exception):
+            eng.warmup()
+        assert eng.metrics.downgrades == 0
+        assert eng.metrics.report()["kernel_downgrades"] == 0
+    finally:
+        eng.close()
+
+
+def test_replica_devices_report_operand_placement():
+    """Each replica's bundle operands live on the device it is pinned
+    to, for both the plain and the degradable forward."""
+    bundle, _ = _tiny_bundle()
+    dev = jax.devices()[0]
+    for kw in ({"use_kernel": False}, {}):
+        with LUTServeEngine(bundle, replicas=2, devices=[dev], **kw) as eng:
+            assert eng.replica_devices() == [{dev}, {dev}]
+
+
 # ---------------------------------------------------------------------------
 # Registry
 
