@@ -15,9 +15,11 @@ in one process:
   4. serve a few hundred requests through ``LUTServeEngine`` after
      ``warmup()`` (route ``fused_kernel_tpu``).
 
-It checks that served predictions equal ``lut_infer.lut_forward`` on the
-same tables for every request, and that the LUT network reproduces the
-quantized model on the test set.
+It checks that the training kernel's gradients match the jnp
+``neuron_leading`` route's at the f32 tolerance of the kernel tests, that
+served predictions equal ``lut_infer.lut_forward`` on the same tables for
+every request, and that the LUT network reproduces the quantized model on
+the test set.
 
 Four chips (``--chips 4``) run only the multi-device paths and what they
 are compared with: ``LUTServeEngine(replicas=4)`` (each replica's bundle
@@ -46,6 +48,7 @@ ROOT = Path(__file__).resolve().parent
 sys.path.insert(0, str(ROOT / "src"))
 
 ARCH = "neuralut-jsc-5l"
+REQUESTS = 300
 REGISTRY = ROOT / ".smoke_registry"
 
 
@@ -119,7 +122,7 @@ def run_one_chip(args, stats: CompileStats) -> None:
     from repro.core.train import train_neuralut
     from repro.data import jsc_synthetic
     from repro.serve import (LUTServeEngine, TableRegistry,
-                             bundle_from_training, make_forward_fn)
+                             bundle_from_training)
 
     cfg = get_config(ARCH)
     check(kernel_compiled(), "Pallas kernels would run interpreted")
@@ -132,15 +135,29 @@ def run_one_chip(args, stats: CompileStats) -> None:
     statics = M.model_static(cfg)
     p0, s0 = M.model_init(cfg, jax.random.PRNGKey(args.seed))
 
-    def loss(p):
-        logits, _, _ = M.model_apply(cfg, p, s0, statics,
-                                     jnp.asarray(xtr[:256]), train=True,
-                                     exec_plan=plan)
-        return M.ce_loss(logits, jnp.asarray(ytr[:256]))
+    def grad_fn(plan):
+        def loss(p):
+            logits, _, _ = M.model_apply(cfg, p, s0, statics,
+                                         jnp.asarray(xtr[:256]), train=True,
+                                         exec_plan=plan)
+            return M.ce_loss(logits, jnp.asarray(ytr[:256]))
+        return jax.jit(jax.grad(loss))
 
-    n_kern = kernel_calls(jax.jit(jax.grad(loss)).lower(p0))
+    grad_k = grad_fn(plan)
+    n_kern = kernel_calls(grad_k.lower(p0))
     check(n_kern >= 2 * cfg.num_layers,
           f"training step lowers {n_kern} compiled kernels")
+    # the kernel's gradients vs the jnp route's, at the f32 tolerance of
+    # tests/test_train_kernel.py
+    gk = jax.tree.leaves(grad_k(p0))
+    gj = jax.tree.leaves(grad_fn(plan_subnet_exec(
+        cfg, purpose="train", route="neuron_leading"))(p0))
+    excess = max(float(np.max(np.abs(a - b) - (3e-5 + 2e-4 * np.abs(b))))
+                 for a, b in zip(map(np.asarray, gk), map(np.asarray, gj)))
+    emit("grad_vs_neuron_leading", leaves=len(gk), worst_excess=excess)
+    check(len(gk) == len(gj) and excess <= 0,
+          f"kernel_train gradients differ from neuron_leading by "
+          f"{excess} beyond tolerance")
     t0 = time.perf_counter()
     params, state, hist = train_neuralut(cfg, xtr, ytr, xte, yte,
                                          epochs=1, batch=256, seed=args.seed)
@@ -194,7 +211,7 @@ def run_one_chip(args, stats: CompileStats) -> None:
 
     # 4. serve
     rng = np.random.default_rng(args.seed)
-    sizes = rng.integers(1, 65, args.requests)
+    sizes = rng.integers(1, 65, REQUESTS)
     rows = [rng.integers(0, len(xte), n) for n in sizes]
     with LUTServeEngine(bundle) as eng:
         route = eng.plan.route
@@ -202,9 +219,10 @@ def run_one_chip(args, stats: CompileStats) -> None:
         interpreted = (detect_backend() != "tpu"
                        if eng.plan.interpret is None else eng.plan.interpret)
         check(not interpreted, "serving plan interprets")
-        x1 = jnp.zeros((8, cfg.in_features), jnp.float32)
-        n_kern = kernel_calls(make_forward_fn(bundle, plan=eng.plan
-                                              ).lower(x1))
+        # the engine's own primary forward, as warmup compiles it
+        primary = eng._executors[0]._forward.primary
+        n_kern = kernel_calls(primary.lower(
+            jnp.zeros((8, cfg.in_features), jnp.float32)))
         check(n_kern == 1, f"serving forward lowers {n_kern} kernels")
         t0 = time.perf_counter()
         eng.warmup()
@@ -268,7 +286,7 @@ def run_four_chips(args, stats: CompileStats) -> None:
     # replica-routed engine: one resident bundle per device
     rng = np.random.default_rng(args.seed)
     rows = [rng.integers(0, len(xte), n)
-            for n in rng.integers(1, 65, args.requests)]
+            for n in rng.integers(1, 65, REQUESTS)]
     with LUTServeEngine(bundle, replicas=4, devices=devices) as eng:
         placed = eng.replica_devices()
         check(placed == [{d} for d in devices],
@@ -338,7 +356,6 @@ def main() -> None:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the multi-device paths")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--requests", type=int, default=300)
     args = ap.parse_args()
 
     from repro.launch.compile_cache import setup_compile_cache

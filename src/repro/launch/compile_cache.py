@@ -9,6 +9,11 @@ inside the checkout, ``.jax_cache/`` (git-ignored).  The path is part of
 what makes a later run find its entries, so it never depends on a
 temporary directory, a process id or the time.
 
+The one setting changed in both cases is the minimum compile time for
+an entry to be written, set to 0: a kernel or a small jitted step
+compiles in well under JAX's default one second and would never be
+cached.
+
 Call :func:`setup_compile_cache` before the first compile.
 """
 from __future__ import annotations
@@ -16,7 +21,8 @@ from __future__ import annotations
 import os
 from pathlib import Path
 
-CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+CHECKOUT = Path(__file__).resolve().parents[3]
+CACHE_DIR = CHECKOUT / ".jax_cache"
 
 
 def setup_compile_cache() -> str:
@@ -25,9 +31,11 @@ def setup_compile_cache() -> str:
 
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
+        if not (CHECKOUT / "src" / "repro").is_dir():
+            raise RuntimeError(
+                f"repro is not imported from a checkout ({CHECKOUT} has no "
+                f"src/repro); set JAX_COMPILATION_CACHE_DIR")
         path = str(CACHE_DIR)
         jax.config.update("jax_compilation_cache_dir", path)
-    # A kernel or a small jitted step compiles in well under JAX's
-    # default one-second threshold and would never be written.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     return path
