@@ -36,6 +36,7 @@ from repro.core import model as M
 from repro.core.exec_plan import plan_subnet_exec
 from repro.core.nl_config import NeuraLUTConfig
 from repro.optim import adamw_init, adamw_update, sgdr_schedule
+from repro.runtime import spans as S
 
 
 def _donate_carries() -> Tuple[int, ...]:
@@ -103,6 +104,7 @@ def _make_epoch_fn(step_fn, n: int, steps_per_epoch: int, batch: int):
     gathered from the device-resident (xd, yd) inside the scan body.
     """
 
+    @jax.named_scope(S.SCOPE_TRAIN_EPOCH)
     def epoch_fn(params, state, opt, key, xd, yd):
         perm = jax.random.permutation(key, n)[: steps_per_epoch * batch]
         idx = perm.reshape(steps_per_epoch, batch)

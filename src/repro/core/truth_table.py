@@ -37,12 +37,14 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import quant, subnet
 from repro.core.exec_plan import SubnetExec, plan_subnet_exec
 from repro.core.lut_infer import pack_tables_jnp, packed_slots
 from repro.core.nl_config import (LUTGraphConfig, NeuraLUTConfig,
                                   is_graph_config)
+from repro.runtime import spans as S
 
 Params = Dict
 
@@ -101,6 +103,7 @@ def _make_sweep(exec_plan: SubnetExec, beta_in: int, beta: int,
         pre, _ = quant.bn_apply(bn_p, bn_s, f, train=False)
         return quant.quant_codes(quant_p, pre, beta)  # (chunk, O) int32
 
+    @jax.named_scope(S.SCOPE_CONVERT_SWEEP)
     def sweep(slot_scale, fnp, bn_p, bn_s, quant_p):
         if nchunks == 1:
             out = eval_chunk(jnp.int32(0), slot_scale, fnp, bn_p, bn_s,
@@ -175,14 +178,20 @@ def _layer_sweep(cfg: NeuraLUTConfig, params: Params, state: Params,
     t = cfg.table_size(layer_idx)
     chunk = _chunk_for(t, batch)
     fn = _get_sweep(cfg, layer_idx, chunk, exec_plan)
-    conn = statics[layer_idx]["conn"]  # (O, F)
-    src_scales = _input_scales(cfg, params, layer_idx)
-    slot_scale = jnp.asarray(src_scales)[jnp.asarray(conn)]  # (O, F)
-    lp = params["layers"][layer_idx]
-    table, packed = fn(slot_scale, lp["fn"], lp["bn"],
-                       state["layers"][layer_idx]["bn"], lp["quant"])
-    return (np.asarray(table),
-            None if packed is None else np.asarray(packed))
+    with TraceAnnotation(S.CONVERT_LAYER, layer=layer_idx,
+                         entries=t * cfg.layer_widths[layer_idx]):
+        with TraceAnnotation(S.CONVERT_PREPARE):
+            conn = statics[layer_idx]["conn"]  # (O, F)
+            src_scales = _input_scales(cfg, params, layer_idx)
+            slot_scale = jnp.asarray(src_scales)[jnp.asarray(conn)]
+        lp = params["layers"][layer_idx]
+        with TraceAnnotation(S.CONVERT_SWEEP):
+            table, packed = fn(slot_scale, lp["fn"], lp["bn"],
+                               state["layers"][layer_idx]["bn"],
+                               lp["quant"])
+        with TraceAnnotation(S.CONVERT_FETCH):
+            return (np.asarray(table),
+                    None if packed is None else np.asarray(packed))
 
 
 def _convert_plan(cfg: NeuraLUTConfig,
@@ -285,15 +294,23 @@ def _graph_node_sweep(cfg: LUTGraphConfig, params: Params, state: Params,
     t = cfg.table_size(idx)
     chunk = _chunk_for(t, batch)
     fn = _get_sweep(cfg, idx, chunk, exec_plan)
-    src_scales = jnp.asarray(_graph_pool_scales(cfg, params, idx))
     conns = node_static_conns(statics[idx])
     lp, ls = params["layers"][idx], state["layers"][idx]
     tables, packeds = [], []
-    for a, (fnp, bnp, bns) in enumerate(node_branch_params(nd, lp, ls)):
-        slot_scale = src_scales[jnp.asarray(conns[a])]  # (O, F)
-        table, packed = fn(slot_scale, fnp, bnp, bns, lp["quant"])
-        tables.append(np.asarray(table))
-        packeds.append(None if packed is None else np.asarray(packed))
+    with TraceAnnotation(S.CONVERT_LAYER, layer=idx,
+                         entries=t * cfg.layer_widths[idx] * len(conns)):
+        with TraceAnnotation(S.CONVERT_PREPARE):
+            src_scales = jnp.asarray(_graph_pool_scales(cfg, params, idx))
+            slot_scales = [src_scales[jnp.asarray(c)]  # (O, F) per branch
+                           for c in conns]
+        for slot_scale, (fnp, bnp, bns) in zip(
+                slot_scales, node_branch_params(nd, lp, ls)):
+            with TraceAnnotation(S.CONVERT_SWEEP):
+                table, packed = fn(slot_scale, fnp, bnp, bns, lp["quant"])
+            with TraceAnnotation(S.CONVERT_FETCH):
+                tables.append(np.asarray(table))
+                packeds.append(None if packed is None
+                               else np.asarray(packed))
     return tables, packeds
 
 

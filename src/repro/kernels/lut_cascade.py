@@ -310,5 +310,6 @@ def lut_cascade(
         out_specs=pl.BlockSpec((block_b, o_last), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, o_last), jnp.int32),
         interpret=interpret,
+        name="lut_cascade",
     )(*operands)
     return out[:b] if pad_b else out

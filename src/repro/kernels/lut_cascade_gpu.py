@@ -112,6 +112,7 @@ def lut_cascade_gpu(
             out_specs=pl.BlockSpec((block_b, o_last), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((bp, o_last), jnp.int32),
             interpret=True,
+            name="lut_cascade_gpu",
         )(*operands)
         return out[:b] if pad_b else out
 
@@ -134,5 +135,6 @@ def lut_cascade_gpu(
         compiler_params=plgpu.GPUCompilerParams(
             dimension_semantics=("parallel",)),
         backend="mosaic_gpu",
+        name="lut_cascade_gpu",
     )(*operands)
     return out[:b] if pad_b else out

@@ -82,5 +82,6 @@ def lut_lookup(
         out_specs=pl.BlockSpec((block_b, block_o), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, op), jnp.int32),
         interpret=interpret,
+        name="lut_gather",
     )(tables.astype(jnp.int32), addr.astype(jnp.int32))
     return out[:b, :o] if (pad_b or pad_o) else out

@@ -109,6 +109,7 @@ def _forward(meta: GradMeta, xg, layer_ws, layer_bs, skip_ws, skip_bs):
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=_interp(meta),
+        name="subnet_train_fwd",
     )(x, *params)
     return outs[0], (x, tuple(outs[1:]), tuple(params[0::2]))
 
@@ -208,6 +209,7 @@ def _backward(meta: GradMeta, g, res):
         out_shape=[jax.ShapeDtypeStruct(s, jnp.float32)
                    for s in [x.shape] + grad_shapes],
         interpret=_interp(meta),
+        name="subnet_train_bwd",
     )(g, x, *acts, *wks)
     dws = tuple(canonical_weight(d) for d in outs[1::2])
     dbs = tuple(d[:, 0, :].T for d in outs[2::2])

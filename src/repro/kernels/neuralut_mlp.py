@@ -182,4 +182,5 @@ def grouped_subnet(
         out_specs=pl.BlockSpec((block_b, block_o), lambda j, i: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, o), jnp.float32),
         interpret=interpret,
+        name="subnet_infer",
     )(x, *params)

@@ -59,10 +59,12 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import lut_infer as LI
 from repro.core.exec_plan import (CascadeExec, detect_backend,
                                   plan_cascade_exec)
+from repro.runtime import spans as S
 from repro.runtime.chaos import ChaosHarness
 from repro.runtime.fault import ReplicaHealthTracker
 from repro.serve.metrics import ServeMetrics
@@ -185,6 +187,7 @@ def make_forward_fn(bundle: ServeBundle, *,
         if plan.use_kernel:
             from repro.kernels.ops import lut_lookup_op
 
+    @jax.named_scope(S.SCOPE_SERVE_STEP)
     def forward(x: jax.Array) -> jax.Array:
         codes = LI.input_codes(cfg, params, x)
         c = codes.astype(jnp.int32)
@@ -467,31 +470,41 @@ class _ReplicaExecutor:
 
     def _serve(self, batch: List[_Request], total: int, depth: int,
                attempts: int = 0) -> None:
-        batch = _drop_expired(batch, self._engine_metrics)
-        if not batch:
-            return
-        total = sum(r.n for r in batch)
-        x = (batch[0].x if len(batch) == 1
-             else np.concatenate([r.x for r in batch], axis=0))
-        try:
-            if self._chaos is not None:
-                self._chaos.check("serve.replica")
-            preds, padded = self._run(x)
-        except Exception as e:
-            self._fail_or_redispatch(batch, total, attempts, e)
-            return
-        self._health.record_success(self.rid)
-        t_done = time.perf_counter()
-        off = 0
-        for r in batch:
-            delivered = _complete(r.future, preds[off:off + r.n])
-            off += r.n
-            if delivered:
-                lat = t_done - r.t_submit
-                self.metrics.record_request(lat, r.n)
-                self._engine_metrics.record_request(lat, r.n)
-        self.metrics.record_batch(total, padded, depth)
-        self._engine_metrics.record_batch(total, padded, depth)
+        t_start = time.perf_counter()
+        with TraceAnnotation(S.SERVE_BATCH, rid=self.rid) as span:
+            batch = _drop_expired(batch, self._engine_metrics)
+            if not batch:
+                return
+            total = sum(r.n for r in batch)
+            if S.recording():
+                span.set_metadata(
+                    requests=len(batch), samples=total,
+                    waits_us=" ".join(str(int((t_start - r.t_submit) * 1e6))
+                                      for r in batch))
+            x = (batch[0].x if len(batch) == 1
+                 else np.concatenate([r.x for r in batch], axis=0))
+            try:
+                if self._chaos is not None:
+                    self._chaos.check("serve.replica")
+                preds, padded = self._run(x)
+            except Exception as e:
+                self._fail_or_redispatch(batch, total, attempts, e)
+                return
+            span.set_metadata(padded=padded,
+                              chunks=-(-total // self._buckets[-1]))
+            self._health.record_success(self.rid)
+            with TraceAnnotation(S.SERVE_RESOLVE):
+                t_done = time.perf_counter()
+                off = 0
+                for r in batch:
+                    delivered = _complete(r.future, preds[off:off + r.n])
+                    off += r.n
+                    if delivered:
+                        lat = t_done - r.t_submit
+                        self.metrics.record_request(lat, r.n)
+                        self._engine_metrics.record_request(lat, r.n)
+            self.metrics.record_batch(total, padded, depth)
+            self._engine_metrics.record_batch(total, padded, depth)
 
     def _run(self, x: np.ndarray) -> Tuple[np.ndarray, int]:
         """Serve (n, F) through bucket-padded jitted calls; returns the
@@ -503,12 +516,19 @@ class _ReplicaExecutor:
         for s in range(0, n, max_bucket):
             chunk = x[s:s + max_bucket]
             b = pick_bucket(chunk.shape[0], self._buckets)
-            if chunk.shape[0] < b:
-                pad = np.zeros((b - chunk.shape[0], x.shape[1]), x.dtype)
-                xc = np.concatenate([chunk, pad], axis=0)
-            else:
-                xc = chunk
-            out = np.asarray(self._forward(self._put(xc)))
+            with TraceAnnotation(S.SERVE_CHUNK, bucket=b):
+                if chunk.shape[0] < b:
+                    pad = np.zeros((b - chunk.shape[0], x.shape[1]),
+                                   x.dtype)
+                    xc = np.concatenate([chunk, pad], axis=0)
+                else:
+                    xc = chunk
+                with TraceAnnotation(S.SERVE_H2D):
+                    xd = self._put(xc)
+                with TraceAnnotation(S.SERVE_STEP):
+                    yd = self._forward(xd)
+                with TraceAnnotation(S.SERVE_FETCH):
+                    out = np.asarray(yd)
             outs.append(out[:chunk.shape[0]])
             padded += b
         return np.concatenate(outs, axis=0), padded
@@ -709,30 +729,33 @@ class LUTServeEngine:
         stop = False
         while not stop:
             try:
-                first = self._queue.get(timeout=0.05)
+                with TraceAnnotation(S.SERVE_AWAIT):
+                    first = self._queue.get(timeout=0.05)
             except queue.Empty:
                 continue
             if first is _STOP:
                 break
-            batch: List[_Request] = [first]
-            total = first.n
-            deadline = time.perf_counter() + self.max_wait_s
-            # Coalesce until the largest bucket is full or the admission
-            # window closes — whichever is first.
-            while total < max_bucket:
-                wait = deadline - time.perf_counter()
-                if wait <= 0:
-                    break
-                try:
-                    nxt = self._queue.get(timeout=wait)
-                except queue.Empty:
-                    break
-                if nxt is _STOP:
-                    stop = True
-                    break
-                batch.append(nxt)
-                total += nxt.n
-            self._route(batch, total)
+            with TraceAnnotation(S.SERVE_COALESCE) as span:
+                batch: List[_Request] = [first]
+                total = first.n
+                deadline = time.perf_counter() + self.max_wait_s
+                # Coalesce until the largest bucket is full or the
+                # admission window closes — whichever is first.
+                while total < max_bucket:
+                    wait = deadline - time.perf_counter()
+                    if wait <= 0:
+                        break
+                    try:
+                        nxt = self._queue.get(timeout=wait)
+                    except queue.Empty:
+                        break
+                    if nxt is _STOP:
+                        stop = True
+                        break
+                    batch.append(nxt)
+                    total += nxt.n
+                span.set_metadata(requests=len(batch), samples=total)
+                self._route(batch, total)
         # fail any requests left behind on shutdown
         while True:
             try:

@@ -41,7 +41,7 @@ class ServeMetrics:
         self._batches = 0
         self._real = 0                      # real samples across batches
         self._padded = 0                    # padded (dispatched) batch slots
-        self._queue_depths: List[int] = []
+        self._depth_sum = 0                 # queue depths summed over batches
         self._admitted = 0                  # requests accepted at the door
         self._shed = 0                      # requests refused (load shedding)
         self._deadline_exceeded = 0         # futures resolved past deadline
@@ -67,7 +67,7 @@ class ServeMetrics:
             self._batches += 1
             self._real += n_real
             self._padded += n_padded
-            self._queue_depths.append(queue_depth)
+            self._depth_sum += queue_depth
 
     # -- admission control (multi-tenant front door, serve/tenants.py) ----
 
@@ -140,7 +140,7 @@ class ServeMetrics:
             lat = sorted(self._lat_s)
             samples, batches = self._samples, self._batches
             real, padded = self._real, self._padded
-            depths = list(self._queue_depths)
+            depth_sum = self._depth_sum
             admitted, shed = self._admitted, self._shed
             deadline = self._deadline_exceeded
             redispatches, downgrades = self._redispatches, self._downgrades
@@ -155,7 +155,7 @@ class ServeMetrics:
             "elapsed_s": elapsed,
             "throughput_sps": samples / elapsed if elapsed > 0 else float("nan"),
             "batch_occupancy": real / padded if padded else float("nan"),
-            "mean_queue_depth": (sum(depths) / len(depths)) if depths
+            "mean_queue_depth": depth_sum / batches if batches
             else float("nan"),
             "admitted": float(admitted),
             "shed": float(shed),

@@ -17,6 +17,7 @@ this file.
 """
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -78,9 +79,16 @@ def _sds(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernels=()):
+    """Compile for the described chip; each of ``kernels`` must name an
+    instruction, the name under which a device trace shows the kernel's
+    calls: ``%lut_cascade.1``, or under autodiff
+    ``%transpose_jvp_subnet_train_bwd__.1``."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for name in kernels:
+        assert re.search(rf"%[\w.]*{name}[\w.]* = ", text), name
     return compiled
 
 
@@ -112,7 +120,7 @@ def test_lut_cascade_compiles(one_chip, mod):
     codes = _sds(one_chip, (SERVE_BATCH, cfg.in_features), jnp.int32)
     _compile(lambda c, s, t: lut_cascade(c, s, t, meta, block_b=8,
                                          interpret=False),
-             codes, sms, pts)
+             codes, sms, pts, kernels=("lut_cascade",))
 
 
 def _subnet_params(cfg, o, f, sharding):
@@ -127,7 +135,8 @@ def test_grouped_subnet_compiles(one_chip, mod, o, f):
     xg = _sds(one_chip, (CONVERT_CHUNK, o, f))
     _compile(lambda x, kw: grouped_subnet(
         x, kw["layer_ws"], kw["layer_bs"], kw["skip_ws"], kw["skip_bs"],
-        skip=cfg.skip, interpret=False), xg, kw)
+        skip=cfg.skip, interpret=False), xg, kw,
+        kernels=("subnet_infer",))
 
 
 @pytest.mark.parametrize("mod,o,f", SUBNET_CASES)
@@ -139,6 +148,6 @@ def test_subnet_train_op_compiles(one_chip, mod, o, f):
     def fwd(p, x):
         return subnet_train_apply(p, x, cfg.skip, interpret=False)
 
-    _compile(fwd, p, xg)
+    _compile(fwd, p, xg, kernels=("subnet_train_fwd",))
     _compile(jax.grad(lambda p, x: jnp.sum(fwd(p, x)), argnums=(0, 1)),
-             p, xg)
+             p, xg, kernels=("subnet_train_fwd", "subnet_train_bwd"))
