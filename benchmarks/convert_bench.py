@@ -71,6 +71,13 @@ FAST_GEOMETRIES = (
 GATE_MIN_ENTRIES = 100_000
 
 
+def _input_scales(cfg, params, layer_idx: int):
+    """Per-source-channel scale of the inputs feeding ``layer_idx``."""
+    if layer_idx == 0:
+        return jnp.exp(params["in_quant"]["log_s"])
+    return jnp.exp(params["layers"][layer_idx - 1]["quant"]["log_s"])
+
+
 def _legacy_convert(cfg, params, state, statics, batch: int = 4096):
     """Pre-refactor converter, vendored (see module docstring)."""
     tables = []
@@ -80,7 +87,7 @@ def _legacy_convert(cfg, params, state, statics, batch: int = 4096):
         conn = statics[layer_idx]["conn"]
         codes = TT.enumerate_codes(beta_in, fan_in)
         t = codes.shape[0]
-        src_scales = TT._input_scales(cfg, params, layer_idx)
+        src_scales = _input_scales(cfg, params, layer_idx)
         offs = 2 ** (beta_in - 1)
         slot_scale = jnp.asarray(src_scales)[jnp.asarray(conn)]
         lp = params["layers"][layer_idx]

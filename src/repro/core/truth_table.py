@@ -19,8 +19,13 @@ cached by their static geometry ``(exec plan, beta_in, beta, F, T,
 chunk)`` (plus operand shapes, via jit), so consecutive layers with
 the same shape share one executable and converting a second model of
 the same family costs zero recompiles — the per-layer ``@jax.jit`` of
-the old converter is gone.  ``convert_cache_stats`` exposes compile
-counts for tests and profiling.
+the old converter is gone.  The sweep also computes each slot's scale
+(``exp`` of the source quantizers' ``log_s``, gathered by the
+connectivity), so a layer is one dispatch and one fetch with no eager
+op before it.  The number of source channels enters only through those
+operands' shapes, so jit compiles once per distinct (source channels,
+layer width) under a static key (hdr-5l: four sweep compiles in all).  ``convert_cache_stats``
+exposes compile counts for tests and profiling.
 
 The hidden function runs through a ``core.exec_plan.SubnetExec``: the
 convert-purpose planner default is the canonical jnp einsum off-TPU
@@ -61,12 +66,17 @@ def enumerate_codes(beta: int, fan_in: int) -> np.ndarray:
     return np.stack(cols, axis=1).astype(np.int32)
 
 
-def _input_scales(cfg: NeuraLUTConfig, params: Params, layer_idx: int
-                  ) -> jax.Array:
-    """Per-source-channel scale of the inputs feeding ``layer_idx``."""
-    if layer_idx == 0:
-        return jnp.exp(params["in_quant"]["log_s"])
-    return jnp.exp(params["layers"][layer_idx - 1]["quant"]["log_s"])
+def _source_log_s(params: Params, buf: int) -> jax.Array:
+    """Log-scale of source buffer ``buf``'s quantizer: buffer 0 is the
+    model input, buffer j+1 is layer (or graph node) j's output.
+
+    An adder-tree node's output code is the *sum* of its branch codes
+    under one shared quantizer, so its dequantization scale is that
+    single quantizer scale — the same formula as a plain code, just at
+    ``beta + log2(A)`` bits (handled by the sweep's ``beta_in``)."""
+    if buf == 0:
+        return params["in_quant"]["log_s"]
+    return params["layers"][buf - 1]["quant"]["log_s"]
 
 
 # ---------------------------------------------------------------------------
@@ -80,10 +90,14 @@ def _make_sweep(exec_plan: SubnetExec, beta_in: int, beta: int,
                 fan_in: int, table_size: int, chunk: int, pack: bool):
     """Build the jitted enumeration sweep for one layer geometry.
 
-    The returned function maps (slot_scale (O, F), fn_params, bn_params,
-    bn_state, quant_params) -> ((O, T) uint16 table, (O, T//P) int32
-    packed words or None).  All enumeration happens on device; the
-    hidden function runs whatever route ``exec_plan`` picked.
+    The returned function maps (src_log_s, conn (O, F) int32,
+    fn_params, bn_params, bn_state, quant_params) -> ((O, T) uint16
+    table, (O, T//P) int32 packed words or None).  ``src_log_s`` is a
+    tuple of the source quantizers' log-scales in pool order (one for a
+    chain layer, one per source for a graph node); the program turns
+    them into each slot's scale itself.  All enumeration happens on
+    device; the hidden function runs whatever route ``exec_plan``
+    picked.
     """
     offs = 2 ** (beta_in - 1)
     mask = 2 ** beta_in - 1
@@ -104,7 +118,10 @@ def _make_sweep(exec_plan: SubnetExec, beta_in: int, beta: int,
         return quant.quant_codes(quant_p, pre, beta)  # (chunk, O) int32
 
     @jax.named_scope(S.SCOPE_CONVERT_SWEEP)
-    def sweep(slot_scale, fnp, bn_p, bn_s, quant_p):
+    def sweep(src_log_s, conn, fnp, bn_p, bn_s, quant_p):
+        log_s = (src_log_s[0] if len(src_log_s) == 1
+                 else jnp.concatenate(src_log_s))
+        slot_scale = jnp.exp(log_s)[conn]  # (O, F): scale of each slot
         if nchunks == 1:
             out = eval_chunk(jnp.int32(0), slot_scale, fnp, bn_p, bn_s,
                              quant_p)  # (T, O)
@@ -173,21 +190,23 @@ def _layer_sweep(cfg: NeuraLUTConfig, params: Params, state: Params,
                  statics: List[Dict], layer_idx: int, *, batch: int,
                  exec_plan: SubnetExec
                  ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
-    """One layer's fused sweep -> ((O, T) uint16, packed int32 | None)."""
+    """One layer's fused sweep -> ((O, T) uint16, packed int32 | None).
+
+    The sweep gets the source quantizer's ``log_s`` (the input's for
+    layer 0, the previous layer's otherwise) and the layer's (O, F)
+    connectivity as they are: it computes the slot scales itself, so
+    its dispatch is the layer's only host work before the fetch."""
     _guard_size(cfg, layer_idx)
     t = cfg.table_size(layer_idx)
     chunk = _chunk_for(t, batch)
     fn = _get_sweep(cfg, layer_idx, chunk, exec_plan)
+    lp = params["layers"][layer_idx]
     with TraceAnnotation(S.CONVERT_LAYER, layer=layer_idx,
                          entries=t * cfg.layer_widths[layer_idx]):
-        with TraceAnnotation(S.CONVERT_PREPARE):
-            conn = statics[layer_idx]["conn"]  # (O, F)
-            src_scales = _input_scales(cfg, params, layer_idx)
-            slot_scale = jnp.asarray(src_scales)[jnp.asarray(conn)]
-        lp = params["layers"][layer_idx]
         with TraceAnnotation(S.CONVERT_SWEEP):
-            table, packed = fn(slot_scale, lp["fn"], lp["bn"],
-                               state["layers"][layer_idx]["bn"],
+            table, packed = fn((_source_log_s(params, layer_idx),),
+                               statics[layer_idx]["conn"], lp["fn"],
+                               lp["bn"], state["layers"][layer_idx]["bn"],
                                lp["quant"])
         with TraceAnnotation(S.CONVERT_FETCH):
             return (np.asarray(table),
@@ -264,30 +283,14 @@ def convert_packed(cfg, params: Params, state: Params,
 # Per-node LUT-graph conversion (DAG topologies)
 
 
-def _graph_pool_scales(cfg: LUTGraphConfig, params: Params, idx: int
-                       ) -> jax.Array:
-    """Per-channel scale of node ``idx``'s concatenated source pool.
-
-    An adder-tree source node's output code is the *sum* of its branch
-    codes under one shared quantizer, so its dequantization scale is
-    that single quantizer scale — the same formula as a plain code, just
-    at ``beta + log2(A)`` bits (handled by the sweep's ``beta_in``)."""
-    parts = []
-    for b in cfg.node_sources(idx):
-        if b == 0:
-            parts.append(jnp.exp(params["in_quant"]["log_s"]))
-        else:
-            parts.append(jnp.exp(params["layers"][b - 1]["quant"]["log_s"]))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-
-
 def _graph_node_sweep(cfg: LUTGraphConfig, params: Params, state: Params,
                       statics: List[Dict], idx: int, *, batch: int,
                       exec_plan: SubnetExec):
     """One node's fused sweeps -> (per-branch [(O, T) uint16],
     per-branch [packed int32 | None]).  Reuses the chain sweep cache:
     the node's geometry key (beta_in, F, T) is all ``_get_sweep`` needs,
-    and every branch of a node shares one compiled executable."""
+    and every branch of a node shares one compiled executable, fed the
+    node's source log-scales and the branch's own connectivity."""
     from repro.core.model import node_branch_params, node_static_conns
     _guard_size(cfg, idx)
     nd = cfg.nodes[idx]
@@ -296,17 +299,16 @@ def _graph_node_sweep(cfg: LUTGraphConfig, params: Params, state: Params,
     fn = _get_sweep(cfg, idx, chunk, exec_plan)
     conns = node_static_conns(statics[idx])
     lp, ls = params["layers"][idx], state["layers"][idx]
+    src_log_s = tuple(_source_log_s(params, b)
+                      for b in cfg.node_sources(idx))
     tables, packeds = [], []
     with TraceAnnotation(S.CONVERT_LAYER, layer=idx,
                          entries=t * cfg.layer_widths[idx] * len(conns)):
-        with TraceAnnotation(S.CONVERT_PREPARE):
-            src_scales = jnp.asarray(_graph_pool_scales(cfg, params, idx))
-            slot_scales = [src_scales[jnp.asarray(c)]  # (O, F) per branch
-                           for c in conns]
-        for slot_scale, (fnp, bnp, bns) in zip(
-                slot_scales, node_branch_params(nd, lp, ls)):
+        for conn, (fnp, bnp, bns) in zip(conns,
+                                         node_branch_params(nd, lp, ls)):
             with TraceAnnotation(S.CONVERT_SWEEP):
-                table, packed = fn(slot_scale, fnp, bnp, bns, lp["quant"])
+                table, packed = fn(src_log_s, conn, fnp, bnp, bns,
+                                   lp["quant"])
             with TraceAnnotation(S.CONVERT_FETCH):
                 tables.append(np.asarray(table))
                 packeds.append(None if packed is None

@@ -31,8 +31,8 @@ Each span nests, on its own thread, inside the one listed as its parent:
   converter (``core/truth_table.py``)
     convert.layer   one layer's (or graph node's) sweep; ``layer``,
                     ``entries``
-    convert.prepare input and slot scales
-    convert.sweep   the jitted sweep's dispatch
+    convert.sweep   the jitted sweep's dispatch (the sweep computes the
+                    slot scales on the device)
     convert.fetch   the tables' copy back to the host
 
 The jitted programs carry ``jax.named_scope``s, so that their device
@@ -54,7 +54,6 @@ SERVE_STEP = "serve.step"
 SERVE_FETCH = "serve.fetch"
 SERVE_RESOLVE = "serve.resolve"
 CONVERT_LAYER = "convert.layer"
-CONVERT_PREPARE = "convert.prepare"
 CONVERT_SWEEP = "convert.sweep"
 CONVERT_FETCH = "convert.fetch"
 
@@ -69,7 +68,6 @@ PARENT: Dict[str, Optional[str]] = {
     SERVE_FETCH: SERVE_CHUNK,
     SERVE_RESOLVE: SERVE_BATCH,
     CONVERT_LAYER: None,
-    CONVERT_PREPARE: CONVERT_LAYER,
     CONVERT_SWEEP: CONVERT_LAYER,
     CONVERT_FETCH: CONVERT_LAYER,
 }

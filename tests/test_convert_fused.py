@@ -7,8 +7,10 @@ that converter (host-side enumeration, fresh ``@jax.jit`` closure per
 layer, chunked numpy round-trips) and every paper geometry is compared
 table-for-table.  Also covered: packed-direct emission == host
 ``pack_tables`` of the unpacked result, compile-count caching across
-layers that share a geometry, the kernel-routed subnet path vs its jnp
-oracle, and serving-ready bundles whose ``prepack`` is a no-op.
+layers that share a geometry, a warm conversion that binds no eager
+primitive (the slot scales are computed inside the sweep), the
+kernel-routed subnet path vs its jnp oracle, and serving-ready bundles
+whose ``prepack`` is a no-op.
 """
 import importlib
 import os
@@ -26,7 +28,8 @@ from benchmarks.convert_bench import _legacy_convert  # noqa: E402
 from repro.core import lut_infer as LI  # noqa: E402
 from repro.core import model as M
 from repro.core import truth_table as TT
-from repro.core.nl_config import NeuraLUTConfig
+from repro.core.nl_config import (INPUT, LUTGraphConfig, LUTNodeSpec,
+                                  NeuraLUTConfig)
 
 ALL_GEOMETRIES = [
     ("neuralut_hdr_5l", "full"), ("neuralut_hdr_5l", "reduced"),
@@ -137,6 +140,56 @@ def test_jit_cache_size_version_safe():
     TT.convert(cfg, params, state, statics)
     stats = TT.convert_cache_stats()
     assert stats and all(n >= 1 for n in stats.values()), stats
+
+
+# ---------------------------------------------------------------------------
+# a warm conversion is one dispatch and one fetch per sweep: the slot
+# scales are computed inside the jitted sweep, never by eager ops
+
+
+# node c concatenates two sources, so its sweep gathers from a pool of
+# two quantizers' scales
+MULTI_SOURCE_DAG = LUTGraphConfig(
+    name="tt-dag", in_features=6, num_classes=3, beta=2, kind="subnet",
+    depth=2, width=4, skip=0,
+    nodes=(LUTNodeSpec(name="a", width=4, fan_in=2, inputs=(INPUT,),
+                       arity=2),
+           LUTNodeSpec(name="b", width=5, fan_in=2, inputs=(INPUT,),
+                       arity=2),
+           LUTNodeSpec(name="c", width=3, fan_in=2, inputs=("a", "b"))))
+
+
+@pytest.mark.parametrize("cfg", [
+    NeuraLUTConfig(name="tt-eager", in_features=6, layer_widths=(8, 5, 3),
+                   num_classes=3, beta=2, fan_in=2, kind="subnet",
+                   depth=2, width=4, skip=0),
+    MULTI_SOURCE_DAG], ids=["chain", "dag"])
+def test_warm_conversion_binds_no_eager_primitive(cfg, monkeypatch):
+    from jax._src import dispatch
+    if not (hasattr(dispatch, "apply_primitive")
+            and hasattr(dispatch, "xla_primitive_callable")):
+        pytest.skip("jax._src.dispatch has no apply_primitive / "
+                    "xla_primitive_callable in this JAX version")
+    statics, params, state = _trained_like(cfg)
+    first = TT.convert_packed(cfg, params, state, statics)  # compiles
+    # Each primitive's eager impl is ``partial(apply_primitive, prim)``,
+    # bound at import, so patching ``apply_primitive`` would see nothing;
+    # every call of it looks up ``xla_primitive_callable`` anew.
+    bound = []
+    lookup = dispatch.xla_primitive_callable
+
+    def counting(prim, **kw):
+        bound.append(prim.name)
+        return lookup(prim, **kw)
+
+    monkeypatch.setattr(dispatch, "xla_primitive_callable", counting)
+    second = TT.convert_packed(cfg, params, state, statics)
+    monkeypatch.undo()
+    assert bound == [], f"eager primitives in a warm conversion: {bound}"
+    first, second = (jax.tree_util.tree_leaves(c) for c in (first, second))
+    assert len(first) == len(second) > 0
+    for a, b in zip(first, second):
+        np.testing.assert_array_equal(a, b)
 
 
 # ---------------------------------------------------------------------------

@@ -144,6 +144,17 @@ def test_converter_spans_nest_per_layer(cfg, tmp_path):
     fetches = sum(1 for n, _, _ in spans if n == S.CONVERT_FETCH)
     assert fetches == sum(len(tb) if isinstance(tb, list) else 1
                           for tb in tables)
+    # each layer holds one sweep and one fetch per branch, and nothing
+    # else: no host work between the layer's start and its dispatch
+    inner = []
+    for name, parent, _ in spans:
+        if name == S.CONVERT_LAYER:
+            inner.append([])
+        elif parent == S.CONVERT_LAYER:
+            inner[-1].append(name)
+    assert inner == [[S.CONVERT_SWEEP, S.CONVERT_FETCH] *
+                     (len(tb) if isinstance(tb, list) else 1)
+                     for tb in tables]
 
 
 def test_recording_follows_the_profiler(tmp_path):
