@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import re
 import sys
 import time
 from dataclasses import dataclass, field
@@ -39,8 +40,8 @@ class BenchError(RuntimeError):
 def load_module(path: Path):
     """Import a file by path (metric and count files are named after
     metrics, which may hold dots)."""
-    name = "bench_" + "_".join(path.relative_to(BENCH).with_suffix("")
-                               .parts).replace(".", "_").replace("-", "_")
+    rel = path.relative_to(BENCH) if path.is_relative_to(BENCH) else path
+    name = "bench_" + re.sub(r"\W", "_", "_".join(rel.with_suffix("").parts))
     if name in sys.modules:
         return sys.modules[name]
     spec = importlib.util.spec_from_file_location(name, path)
@@ -64,6 +65,7 @@ def reference(conf: Dict):
 @dataclass
 class Cell:
     root: Path
+    bench: Path           # where its traffic, limits, drivers and metrics are
     workload: Dict
     config: Dict          # the configs entry of BENCHMARK.json
     conf: Dict            # the configuration file's contents
@@ -105,7 +107,7 @@ def resolve(spec: Dict, root: Path, workload: str,
     if not (bench / "drivers" / f"{traffic['driver']}.py").is_file():
         raise BenchError(f"traffic {w['traffic']} names driver "
                          f"{traffic['driver']}, which does not exist")
-    return Cell(root, w, config, conf, traffic, limits, e2e, layer)
+    return Cell(root, bench, w, config, conf, traffic, limits, e2e, layer)
 
 
 @dataclass
@@ -179,20 +181,21 @@ TRACE_SECONDS = 4.0
 
 def run(spec: Dict, root: Path, workload: str, seed: int, seconds: float,
         trace: bool, *, t_start: float, strict: bool = True,
-        fault: str = "") -> Dict:
+        fault: str = "", bench: Path = BENCH) -> Dict:
     """Run one cell and return the result line's object.  ``strict``
     (always on in benchmark runs) requires the TPU, its planned kernel
     routes and compiled kernels; the CPU tests turn it off.  ``fault``
     plants one of a driver's known faults under the timed path, for the
-    tests that show ``correct`` comes out false."""
+    tests that show ``correct`` comes out false.  ``bench`` is the
+    directory the cell's files are found in (tests give a copy)."""
     from benchkit.compile_stats import CompileStats
     from benchkit.model import Geometry
     from benchkit import trace as T
 
-    cell = resolve(spec, root, workload)
+    cell = resolve(spec, root, workload, bench)
     device = device_info(strict, cell.workload["chips"])
     stats = CompileStats()
-    driver = load_module(BENCH / "drivers"
+    driver = load_module(cell.bench / "drivers"
                          / f"{cell.traffic['driver']}.py")
     drv = driver.Driver(cell, seed, strict=strict, fault=fault)
     drv.setup()
@@ -220,7 +223,7 @@ def run(spec: Dict, root: Path, workload: str, seed: int, seconds: float,
                       else {"flops_per_s": 1.0, "hbm_bytes_per_s": 1.0})
         metrics = {}
         for m in cell.per_layer:
-            value = load_module(BENCH / "metrics"
+            value = load_module(cell.bench / "metrics"
                                 / f"{m['name']}.py").read(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}

@@ -1,11 +1,14 @@
 """A cell's model, made by the benchmark from its configuration file.
 
 The configuration file (``bench/configs/<name>.json``) holds the sizes as
-they are run.  From it and a seed this module makes everything both sides
-of a comparison need: connectivity, serving tables and quantizer scales,
-training and conversion parameters.  The program receives these arrays
-through its own entry points; the reference (``bench/reference``) gets the
-same arrays, so it takes nothing the program made.
+they are run: a chain's ``layer_widths``, or a LUT graph's ``nodes``
+(each with ``name``, ``width``, ``fan_in``, ``inputs`` and ``arity``, as
+the program's ``LUTNodeSpec``).  From it and a seed this module makes
+everything both sides of a comparison need: connectivity, serving
+tables and quantizer scales, training and conversion parameters.  The
+program receives these arrays through its own entry points; the
+reference (``bench/reference``) gets the same arrays, so it takes nothing
+the program made.
 
 Connectivity, serving tables and scales come from the configuration's
 fixed ``model_seed``: the serving forward and the training epoch close
@@ -23,11 +26,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# The fields of the program's NeuraLUTConfig that a configuration file
-# states; every one of them is compared with the registered architecture.
+# The fields of the program's NeuraLUTConfig that a chain file states, and
+# of its LUTGraphConfig that a graph file states besides ``nodes``; every
+# one of them is compared with the registered architecture.
 CONFIG_FIELDS = ("name", "in_features", "layer_widths", "num_classes",
                  "beta", "fan_in", "kind", "depth", "width", "skip",
                  "degree", "beta_in", "fan_in_0", "bn_momentum", "family")
+GRAPH_FIELDS = ("name", "in_features", "num_classes", "beta", "kind",
+                "depth", "width", "skip", "degree", "beta_in", "bn_momentum",
+                "family")
+# The source name of a graph node that reads the model's quantized inputs.
+INPUT = "input"
 
 
 def seed_int(seed: int, *salt: int) -> int:
@@ -42,13 +51,32 @@ def seed_key(seed: int, *salt: int):
 
 
 @dataclass(frozen=True)
+class Node:
+    """One node of a LUT graph: ``width`` neurons, each the sum of
+    ``arity`` branches; a branch looks its beta-bit code up in its own
+    table, addressed by ``fan_in`` codes of ``in_bits`` bits drawn from
+    the node's source pool (the buffers ``inputs`` concatenated in that
+    order; buffer 0 is the quantized input, buffer j + 1 node j's
+    output).  The summed code has ``out_bits`` = beta + log2(arity)."""
+    width: int
+    fan_in: int
+    arity: int
+    inputs: Tuple[int, ...]
+    in_bits: int
+    out_bits: int
+
+
+@dataclass(frozen=True)
 class Geometry:
-    """Sizes of a chain NeuraLUT network, read from the configuration
-    file alone."""
+    """Sizes of a NeuraLUT network as a graph of LUT nodes, read from the
+    configuration file alone.  A chain file (``layer_widths``) reads as
+    the degenerate graph, node i reading node i - 1; a graph file states
+    its ``nodes``.  Per-layer accessors take a node's index; connectivity
+    and tables come one per branch, node by node (for a chain, one per
+    layer)."""
     in_features: int
-    widths: Tuple[int, ...]
-    fan_ins: Tuple[int, ...]
-    in_bits: Tuple[int, ...]
+    input_bits: int
+    nodes: Tuple[Node, ...]
     beta: int
     depth: int
     width: int
@@ -57,41 +85,122 @@ class Geometry:
 
     @classmethod
     def from_conf(cls, conf: Dict) -> "Geometry":
-        widths = tuple(conf["layer_widths"])
-        n = len(widths)
-        f0 = conf.get("fan_in_0") or conf["fan_in"]
-        b0 = conf.get("beta_in") or conf["beta"]
-        return cls(in_features=conf["in_features"], widths=widths,
-                   fan_ins=(f0,) + (conf["fan_in"],) * (n - 1),
-                   in_bits=(b0,) + (conf["beta"],) * (n - 1),
-                   beta=conf["beta"], depth=conf["depth"],
+        beta = conf["beta"]
+        b0 = conf.get("beta_in") or beta
+        if "nodes" in conf:
+            nodes = _graph_nodes(conf["nodes"], b0, beta)
+        else:
+            f0 = conf.get("fan_in_0") or conf["fan_in"]
+            nodes = tuple(
+                Node(width=o, fan_in=f0 if i == 0 else conf["fan_in"],
+                     arity=1, inputs=(i,), in_bits=b0 if i == 0 else beta,
+                     out_bits=beta)
+                for i, o in enumerate(conf["layer_widths"]))
+        return cls(in_features=conf["in_features"], input_bits=b0,
+                   nodes=nodes, beta=beta, depth=conf["depth"],
                    width=conf["width"], skip=conf["skip"],
                    momentum=conf["bn_momentum"])
 
     @property
+    def widths(self) -> Tuple[int, ...]:
+        return tuple(n.width for n in self.nodes)
+
+    @property
+    def fan_ins(self) -> Tuple[int, ...]:
+        return tuple(n.fan_in for n in self.nodes)
+
+    @property
+    def in_bits(self) -> Tuple[int, ...]:
+        return tuple(n.in_bits for n in self.nodes)
+
+    @property
+    def num_classes(self) -> int:
+        return self.nodes[-1].width
+
+    @property
     def sources(self) -> Tuple[int, ...]:
-        return (self.in_features,) + self.widths[:-1]
+        """Width of each node's source pool."""
+        buffers = (self.in_features,) + self.widths
+        return tuple(sum(buffers[b] for b in n.inputs) for n in self.nodes)
 
     def table_size(self, i: int) -> int:
+        """Entries of each branch table of node ``i``."""
         return 2 ** (self.in_bits[i] * self.fan_ins[i])
 
     @property
     def table_entries(self) -> int:
-        return sum(o * self.table_size(i) for i, o in enumerate(self.widths))
+        return sum(n.width * n.arity * self.table_size(i)
+                   for i, n in enumerate(self.nodes))
+
+    @property
+    def is_chain(self) -> bool:
+        """True for a plain cascade: every node one branch, reading only
+        the buffer before it (node 0 the input)."""
+        return all(n.arity == 1 and n.inputs == (i,)
+                   for i, n in enumerate(self.nodes))
 
     def mlp_widths(self, i: int) -> List[int]:
         """n_0 = F, n_1..n_{L-1} = N, n_L = 1 (one output per neuron)."""
         return [self.fan_ins[i]] + [self.width] * (self.depth - 1) + [1]
 
+    def per_node(self, branches: List) -> List[List]:
+        """A list with one entry per branch, node by node, cut into one
+        list per node."""
+        out, b = [], 0
+        for n in self.nodes:
+            out.append(list(branches[b:b + n.arity]))
+            b += n.arity
+        return out
+
+
+def _graph_nodes(specs: List[Dict], input_bits: int, beta: int
+                 ) -> Tuple[Node, ...]:
+    """The nodes of a graph file, in its (topological) order."""
+    buf = {INPUT: 0}
+    bits = [input_bits]
+    nodes = []
+    for spec in specs:
+        arity = spec.get("arity", 1)
+        if arity < 1 or arity & (arity - 1):
+            raise ValueError(f"node {spec['name']}: arity {arity} is not a "
+                             f"power of two")
+        missing = [s for s in spec["inputs"] if s not in buf]
+        if missing:
+            raise ValueError(f"node {spec['name']} reads {missing}, which "
+                             f"are not the input or an earlier node")
+        inputs = tuple(buf[s] for s in spec["inputs"])
+        if len({bits[b] for b in inputs}) != 1:
+            raise ValueError(f"node {spec['name']} concatenates sources "
+                             f"of unequal bit widths")
+        node = Node(width=spec["width"], fan_in=spec["fan_in"], arity=arity,
+                    inputs=inputs, in_bits=bits[inputs[0]],
+                    out_bits=beta + arity.bit_length() - 1)
+        buf[spec["name"]] = len(nodes) + 1
+        bits.append(node.out_bits)
+        nodes.append(node)
+    return tuple(nodes)
+
 
 def program_config(conf: Dict):
-    """The program's config object for this file; when the file names a
-    registered ``arch``, every stated field must equal the registered
-    one (the benchmark runs the architecture it says it runs)."""
-    from repro.core.nl_config import NeuraLUTConfig
-    fields = {k: conf[k] for k in CONFIG_FIELDS if k in conf}
-    fields["layer_widths"] = tuple(fields["layer_widths"])
-    cfg = NeuraLUTConfig(**fields)
+    """The program's config object for this file: a ``NeuraLUTConfig`` for
+    a chain file, a ``LUTGraphConfig`` for a graph file.  When the file
+    names a registered ``arch``, every stated field must equal the
+    registered one (the benchmark runs the architecture it says it
+    runs)."""
+    if "nodes" in conf:
+        from repro.core.nl_config import LUTGraphConfig, LUTNodeSpec
+        fields = {k: conf[k] for k in GRAPH_FIELDS if k in conf}
+        fields["nodes"] = tuple(
+            LUTNodeSpec(name=n["name"], width=n["width"],
+                        fan_in=n["fan_in"], inputs=tuple(n["inputs"]),
+                        arity=n.get("arity", 1))
+            for n in conf["nodes"])
+        cfg = LUTGraphConfig(**fields)
+    else:
+        from repro.core.nl_config import NeuraLUTConfig
+        fields = {k: conf[k] for k in CONFIG_FIELDS if k in conf}
+        fields["layer_widths"] = tuple(fields["layer_widths"])
+        cfg = NeuraLUTConfig(**fields)
     if conf.get("arch"):
         from repro.config import get_config
         reg = get_config(conf["arch"])
@@ -101,32 +210,48 @@ def program_config(conf: Dict):
     return cfg
 
 
+def chain_config(conf: Dict):
+    """The program's ``NeuraLUTConfig`` of a chain, whether its file states
+    ``layer_widths`` or ``nodes`` that form a chain (``Geometry.is_chain``);
+    the training and conversion drivers take chains alone."""
+    from repro.core.nl_config import is_graph_config
+    cfg = program_config(conf)
+    return cfg.as_chain() if is_graph_config(cfg) else cfg
+
+
 def connectivity(geom: Geometry, model_seed: int) -> List[np.ndarray]:
-    """(O, F) int32 per layer: F distinct sources per neuron."""
+    """(O, F) int32 per branch, node by node: F distinct sources of the
+    node's pool per neuron."""
     out = []
-    for i, (o, f) in enumerate(zip(geom.widths, geom.fan_ins)):
-        rng = np.random.default_rng([model_seed, 1, i])
-        order = np.argsort(rng.random((o, geom.sources[i])), axis=1)
-        out.append(order[:, :f].astype(np.int32))
+    for i, (n, pool) in enumerate(zip(geom.nodes, geom.sources)):
+        for a in range(n.arity):
+            # numpy pads a seed with zeros: branch 0 draws what a
+            # chain layer drew from [model_seed, 1, i]
+            rng = np.random.default_rng([model_seed, 1, i, a])
+            order = np.argsort(rng.random((n.width, pool)), axis=1)
+            out.append(order[:, :n.fan_in].astype(np.int32))
     return out
 
 
 def serving_model(geom: Geometry, conf: Dict) -> Dict:
-    """Random tables in the served type (uint16 codes) and seeded
-    quantizer scales, made on the device in one call."""
+    """Random tables in the served type (uint16 codes), one per branch,
+    and seeded quantizer scales, one vector per node, made on the device
+    in one call."""
     seed = conf["model_seed"]
     in_scale = conf["serve_input_scale"]
+    shapes = [(n.width, geom.table_size(i))
+              for i, n in enumerate(geom.nodes) for _ in range(n.arity)]
 
     @jax.jit
     def make(key):
-        ks = jax.random.split(key, len(geom.widths) + 3)
-        tables = [jax.random.randint(ks[i], (o, geom.table_size(i)), 0,
+        ks = jax.random.split(key, len(shapes) + 3)
+        tables = [jax.random.randint(ks[b], shape, 0,
                                      2 ** geom.beta).astype(jnp.uint16)
-                  for i, o in enumerate(geom.widths)]
+                  for b, shape in enumerate(shapes)]
         in_log_s = jnp.log(in_scale * jax.random.uniform(
             ks[-3], (geom.in_features,), minval=0.8, maxval=1.2))
         out_log_s = jnp.log(jax.random.uniform(
-            ks[-2], (geom.widths[-1],), minval=0.5, maxval=1.5))
+            ks[-2], (geom.num_classes,), minval=0.5, maxval=1.5))
         hidden_log_s = [jnp.full((o,), jnp.log(0.25), jnp.float32)
                         for o in geom.widths[:-1]]
         return tables, in_log_s, hidden_log_s + [out_log_s]
@@ -139,13 +264,17 @@ def serving_model(geom: Geometry, conf: Dict) -> Dict:
 
 
 def reference_model(geom: Geometry, served: Dict) -> Dict:
-    """The serving model as the reference takes it (device arrays)."""
+    """The serving model as the reference takes it (device arrays, one
+    table and one connectivity per branch, each node's sources, input
+    bits and arity, and the input and class code widths)."""
     return {"tables": [jnp.asarray(t.astype(np.int32))
                        for t in served["tables"]],
             "conns": [jnp.asarray(c) for c in served["conns"]],
+            "nodes": [{"inputs": n.inputs, "in_bits": n.in_bits,
+                       "arity": n.arity} for n in geom.nodes],
             "in_log_s": jnp.asarray(served["in_log_s"]),
             "out_log_s": jnp.asarray(served["layer_log_s"][-1]),
-            "in_bits": geom.in_bits, "beta": geom.beta}
+            "input_bits": geom.input_bits, "beta": geom.beta}
 
 
 def fn_params(geom: Geometry, i: int, key, bias_sd: float) -> Dict:

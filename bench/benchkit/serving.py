@@ -73,14 +73,21 @@ class ServingCell:
 
     def build(self) -> None:
         import jax.numpy as jnp
+        from repro.core.nl_config import is_graph_config
         from repro.serve import LUTServeEngine
         from repro.serve.registry import ServeBundle
 
         cfg = program_config(self.cell.conf)
         self.served = serving_model(self.geom, self.cell.conf)
+        tables, conns = self.served["tables"], self.served["conns"]
+        if is_graph_config(cfg):
+            # a graph bundle: per node, a list of its branches
+            tables = self.geom.per_node(tables)
+            statics = [{"conns": c} for c in self.geom.per_node(conns)]
+        else:
+            statics = [{"conn": c} for c in conns]
         bundle = ServeBundle(
-            cfg=cfg, tables=self.served["tables"],
-            statics=[{"conn": c} for c in self.served["conns"]],
+            cfg=cfg, tables=tables, statics=statics,
             in_log_s=self.served["in_log_s"],
             layer_log_s=self.served["layer_log_s"])
         eng = LUTServeEngine(bundle, **self.traffic.get("engine", {}))

@@ -2,10 +2,13 @@
 (``kernels/lut_cascade``).
 
 Operations: one multiply-add per input bit-field of every lookup (forming
-its address), plus one per lookup, two operations each.  Bytes: the
-input codes read and the output codes written (int32), and every packed
-table read once per call.  The shift-matmul and the mux tree are how the
-kernel does it, not what the algorithm needs, and are not counted.
+its address), plus one per lookup, two operations each, and one addition
+per branch beyond the first of an adder node (summing its branches'
+codes): 2·Σ O·A·(F+1) + Σ O·(A−1) per sample over the nodes.  Bytes: the
+input codes read and the class codes written (int32), and every branch's
+packed table read once per call.  The shift-matmul and the mux tree are
+how the kernel does it, not what the algorithm needs, and are not
+counted.
 """
 from __future__ import annotations
 
@@ -18,7 +21,8 @@ TRACE_PATTERN = r"^tpu_custom_call$"
 
 
 def ops_per_sample(geom) -> int:
-    return 2 * sum(o * (f + 1) for o, f in zip(geom.widths, geom.fan_ins))
+    return sum(2 * n.width * n.arity * (n.fan_in + 1)
+               + n.width * (n.arity - 1) for n in geom.nodes)
 
 
 def packed_words(geom, i: int) -> int:
@@ -27,10 +31,10 @@ def packed_words(geom, i: int) -> int:
 
 
 def table_bytes(geom) -> int:
-    return sum(4 * o * packed_words(geom, i)
-               for i, o in enumerate(geom.widths))
+    return sum(4 * n.width * n.arity * packed_words(geom, i)
+               for i, n in enumerate(geom.nodes))
 
 
 def call_bytes(geom, slots: int) -> int:
     return table_bytes(geom) + 4 * slots * (geom.in_features
-                                            + geom.widths[-1])
+                                            + geom.num_classes)

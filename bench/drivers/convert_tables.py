@@ -17,8 +17,8 @@ import time
 import numpy as np
 
 from benchkit.cell import BenchError, Check, Window, reference
-from benchkit.model import (Geometry, connectivity, convert_params,
-                            fn_params, program_config)
+from benchkit.model import (Geometry, chain_config, connectivity,
+                            convert_params, fn_params)
 from benchkit.compile_stats import kernel_calls
 
 
@@ -28,6 +28,10 @@ class Driver:
         self.fault = fault
         self.traffic = cell.traffic
         self.geom = Geometry.from_conf(cell.conf)
+        if not self.geom.is_chain:
+            raise BenchError(f"{cell.conf['name']} is a LUT graph "
+                             f"configuration: it has no conversion "
+                             f"driver yet")
 
     def setup(self) -> None:
         import jax
@@ -35,7 +39,7 @@ class Driver:
         from repro.core import truth_table as TT
         from repro.core.exec_plan import kernel_compiled, plan_subnet_exec
 
-        cfg = self.cfg = program_config(self.cell.conf)
+        cfg = self.cfg = chain_config(self.cell.conf)
         plan = plan_subnet_exec(cfg, purpose="convert")
         if self.strict:
             if plan.route != self.traffic["route"] or not kernel_compiled():

@@ -27,7 +27,7 @@ import numpy as np
 
 from benchkit import data
 from benchkit.cell import BenchError, Check, Window, reference
-from benchkit.model import (Geometry, connectivity, program_config,
+from benchkit.model import (Geometry, chain_config, connectivity,
                             seed_key, seed_int, train_params)
 from benchkit.compile_stats import kernel_calls
 
@@ -64,6 +64,10 @@ class Driver:
         self.fault = fault
         self.traffic = cell.traffic
         self.geom = Geometry.from_conf(cell.conf)
+        if not self.geom.is_chain:
+            raise BenchError(f"{cell.conf['name']} is a LUT graph "
+                             f"configuration: it has no training "
+                             f"driver yet")
 
     def _build(self):
         import jax
@@ -73,7 +77,7 @@ class Driver:
         from repro.optim import adamw_init
 
         t = self.traffic
-        cfg = program_config(self.cell.conf)
+        cfg = chain_config(self.cell.conf)
         plan = plan_subnet_exec(cfg, purpose="train")
         if self.strict and (plan.route != t["route"]
                             or not kernel_compiled()):

@@ -17,7 +17,7 @@ def read(ctx):
     slots = occ["padded"]
     ops = slots * c.ops_per_sample(ctx.geom)
     nbytes = calls * c.table_bytes(ctx.geom) + 4 * slots * (
-        ctx.geom.in_features + ctx.geom.widths[-1])
+        ctx.geom.in_features + ctx.geom.num_classes)
     least = max(ops / ctx.peaks["flops_per_s"],
                 nbytes / ctx.peaks["hbm_bytes_per_s"])
     return 100.0 * least / secs

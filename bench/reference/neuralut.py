@@ -8,7 +8,10 @@ and conversion are straightforward ``jax.numpy`` in float32; every
 contraction of the hidden function runs at ``Precision.HIGHEST`` unless a
 control asks for a lower precision (``PRECISIONS``).  Serving quantizes
 and compares class values in float64 on the host (a device's ``exp`` and
-division are approximate) and runs the integer cascade on the device.
+division are approximate) and runs the integer cascade on the device:
+a graph of LUT nodes, whose adder neurons sum their branches' codes
+(PolyLUT-Add, arXiv:2406.04910); a chain is the graph of one-branch
+nodes that each read the one before.
 
 Parameters use the same nested layout as the configuration's pytree:
 
@@ -115,22 +118,37 @@ def subnet(fn: Dict, x, skip: int, precision: str = "highest"):
 
 
 # ---------------------------------------------------------------------------
-# serving: the LUT cascade
+# serving: the LUT cascade, a graph of table lookups
 
 
-def lut_forward(codes, tables: Sequence, conns: Sequence,
-                in_bits: Sequence[int]):
-    """codes (B, in) int32 -> (B, classes) output codes.  Each neuron's
-    address is its F input codes concatenated, slot 0 most significant."""
-    c = codes
-    for tbl, conn, bits in zip(tables, conns, in_bits):
-        f = conn.shape[1]
-        g = c[:, conn]                                      # (B, O, F)
-        addr = jnp.zeros(g.shape[:2], jnp.int32)
-        for j in range(f):
-            addr = addr * (1 << bits) + g[..., j]
-        c = jnp.take_along_axis(tbl.T, addr, axis=0).astype(jnp.int32)
-    return c
+def lookup(pool, table, conn, bits: int):
+    """One branch of a node: (B, W) source codes -> (B, O) table codes.
+    Each neuron's address is its F source codes concatenated, slot 0 most
+    significant."""
+    g = pool[:, conn]                                       # (B, O, F)
+    addr = jnp.zeros(g.shape[:2], jnp.int32)
+    for j in range(conn.shape[1]):
+        addr = addr * (1 << bits) + g[..., j]
+    return jnp.take_along_axis(table.T, addr, axis=0).astype(jnp.int32)
+
+
+def lut_forward(codes, nodes: Sequence[Dict], tables: Sequence,
+                conns: Sequence):
+    """codes (B, in) int32 -> (B, classes) output codes, node by node.
+    ``nodes[i]`` names the buffers node i reads (``inputs``: 0 is
+    ``codes``, j + 1 node j's output), concatenated in that order, their
+    code width (``in_bits``) and the node's ``arity``; ``tables`` and
+    ``conns`` hold one entry per branch, node by node.  A neuron's code is
+    the sum of its branches' codes (one branch in a chain)."""
+    bufs = [codes]
+    b = 0
+    for nd in nodes:
+        pool = jnp.concatenate([bufs[i] for i in nd["inputs"]], axis=1)
+        bufs.append(sum(lookup(pool, tables[b + a], conns[b + a],
+                               nd["in_bits"])
+                        for a in range(nd["arity"])))
+        b += nd["arity"]
+    return bufs[-1]
 
 
 # The served quantizer and class values are float32 arithmetic.  Where a
@@ -164,7 +182,7 @@ def lut_outputs(codes: np.ndarray, model: Dict,
                 block: int = 16384) -> np.ndarray:
     """The cascade on the device (integer arithmetic, exact on every
     backend), in blocks of one shape: (N, in) -> (N, classes) codes."""
-    fn = jax.jit(lambda c, t, k: lut_forward(c, t, k, model["in_bits"]))
+    fn = jax.jit(lambda c, t, k: lut_forward(c, model["nodes"], t, k))
     block = min(block, len(codes))
     out = []
     for s in range(0, len(codes), block):
@@ -188,10 +206,10 @@ def served_gaps(x: np.ndarray, served: np.ndarray, model: Dict, *,
     ``lowp_inputs`` puts the control in the program's place: the class
     the reference picks from its inputs rounded to bfloat16."""
     x = np.asarray(x, np.float32)
-    bits, beta = model["in_bits"][0], model["beta"]
+    bits = model["input_bits"]
     in_log_s = np.asarray(model["in_log_s"])
     out_s = np.exp(np.asarray(model["out_log_s"], np.float64))
-    half = 2 ** (beta - 1)
+    half = 2 ** (model["beta"] - 1)
     codes, alt = served_input_codes(x, in_log_s, bits)
     rows, owner = [codes], [np.arange(len(x))]
     flip = alt != codes

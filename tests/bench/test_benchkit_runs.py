@@ -27,6 +27,9 @@ def tiny(tmp_path_factory):
     root = tmp_path_factory.mktemp("tiny")
     spec = json.loads(json.dumps(SPEC))
     for c in spec["configs"]:
+        if c["name"] not in TINY:      # a configuration a later PR added
+            c["file"] = str(ROOT / c["file"])
+            continue
         conf = json.loads((ROOT / c["file"]).read_text())
         conf.update(TINY[c["name"]], arch=None)
         path = root / f"{c['name']}.json"
