@@ -1,15 +1,17 @@
 """Smoke run of the NeuraLUT toolflow on a TPU, through its entry points.
 
     python chip_smoke.py              # one chip: train -> convert -> serve
+    python chip_smoke.py --arch polylut-add-jsc-xl
     python chip_smoke.py --chips 4    # four chips: multi-device paths only
 
-One chip, at the full width of neuralut-jsc-5l (configs/neuralut_jsc_5l.py),
-in one process:
+One chip, at the full width of a registered LUT configuration (``--arch``,
+default neuralut-jsc-5l; a chain or a LUT graph such as the PolyLUT-Add
+adder trees), in one process:
 
   1. train one epoch on ``data.jsc_synthetic`` from ``--seed`` through
      ``core.train.train_neuralut`` (route ``kernel_train``);
   2. convert to bit-packed truth tables with ``truth_table.convert_packed``
-     (route ``kernel_infer``);
+     (a graph's ``convert_graph_packed``; route ``kernel_infer``);
   3. save the bundle to an emptied registry directory and load it back
      with ``load(verify=True)``;
   4. serve a few hundred requests through ``LUTServeEngine`` after
@@ -17,16 +19,17 @@ in one process:
 
 It checks that the training kernel's gradients match the jnp
 ``neuron_leading`` route's at the f32 tolerance of the kernel tests, that
-served predictions equal ``lut_infer.lut_forward`` on the same tables for
-every request, and that the LUT network reproduces the quantized model on
-the test set.
+served predictions equal ``lut_infer.lut_forward`` (a graph's
+``graph_lut_forward``) on the same tables for every request, and that the
+LUT network reproduces the quantized model on the test set.
 
-Four chips (``--chips 4``) run only the multi-device paths and what they
-are compared with: ``LUTServeEngine(replicas=4)`` (each replica's bundle
-on its own device), ``make_sharded_forward_fn`` in the ``replicated`` and
-``o_sharded`` modes, all three bit-exact against ``lut_forward``, and a
-small ``run_pareto_sweep`` on ``make_sweep_mesh(4)`` held to the frontier
-agreement of the single-program ensemble trainer.
+Four chips (``--chips 4``, a chain) run only the multi-device paths and
+what they are compared with: ``LUTServeEngine(replicas=4)`` (each
+replica's bundle on its own device), ``make_sharded_forward_fn`` in the
+``replicated`` and ``o_sharded`` modes, all three bit-exact against
+``lut_forward``, and a small ``run_pareto_sweep`` on
+``make_sweep_mesh(4)`` held to the frontier agreement of the
+single-program ensemble trainer.
 
 Each phase prints one JSON line.  The last line of standard output is
 ``{"ok": true, "device": {...}}`` and is printed only when every check
@@ -54,6 +57,20 @@ REGISTRY = ROOT / ".smoke_registry"
 
 class SmokeFailure(RuntimeError):
     pass
+
+
+def lut_config(arch: str):
+    """The registered LUT configuration ``arch`` (a chain's
+    ``NeuraLUTConfig`` or a graph's ``LUTGraphConfig``)."""
+    from repro.config import get_config
+    from repro.core.nl_config import LUTGraphConfig, NeuraLUTConfig
+    try:
+        cfg = get_config(arch)
+    except KeyError as e:
+        raise SystemExit(f"chip_smoke: {e.args[0]}")
+    if not isinstance(cfg, (NeuraLUTConfig, LUTGraphConfig)):
+        raise SystemExit(f"chip_smoke: {arch} is not a LUT configuration")
+    return cfg
 
 
 def check(cond: bool, msg: str) -> None:
@@ -95,14 +112,13 @@ def kernel_calls(lowered) -> int:
 
 
 def oracle_preds(cfg, bundle, x):
-    """lut_forward on the bundle's own tables, on the device."""
+    """lut_forward (a graph's graph_lut_forward) on the bundle's own
+    tables, on the device."""
     import jax.numpy as jnp
     import numpy as np
     from repro.core import lut_infer as LI
-    params = bundle.serve_params()
-    codes = LI.input_codes(cfg, params, jnp.asarray(x))
-    out = LI.lut_forward(cfg, bundle.tables, bundle.statics, codes)
-    return np.asarray(jnp.argmax(LI.class_values(cfg, params, out), -1))
+    return np.asarray(LI.predict(cfg, bundle.serve_params(), bundle.tables,
+                                 bundle.statics, jnp.asarray(x)))
 
 
 # ---------------------------------------------------------------------------
@@ -113,18 +129,18 @@ def run_one_chip(args, stats: CompileStats) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from repro.config import get_config
     from repro.core import lut_infer as LI
     from repro.core import model as M
     from repro.core import truth_table as TT
     from repro.core.exec_plan import (detect_backend, kernel_compiled,
                                       plan_subnet_exec)
+    from repro.core.nl_config import is_graph_config
     from repro.core.train import train_neuralut
     from repro.data import jsc_synthetic
     from repro.serve import (LUTServeEngine, TableRegistry,
                              bundle_from_training)
 
-    cfg = get_config(ARCH)
+    cfg = args.cfg
     check(kernel_compiled(), "Pallas kernels would run interpreted")
     xtr, ytr = jsc_synthetic(20000, seed=args.seed)
     xte, yte = jsc_synthetic(4000, seed=args.seed + 1)
@@ -145,7 +161,9 @@ def run_one_chip(args, stats: CompileStats) -> None:
 
     grad_k = grad_fn(plan)
     n_kern = kernel_calls(grad_k.lower(p0))
-    check(n_kern >= 2 * cfg.num_layers,
+    # a forward and a backward kernel per branch (a chain layer is one)
+    branches = sum(len(M.node_static_conns(s)) for s in statics)
+    check(n_kern >= 2 * branches,
           f"training step lowers {n_kern} compiled kernels")
     # the kernel's gradients vs the jnp route's, at the f32 tolerance of
     # tests/test_train_kernel.py
@@ -171,15 +189,18 @@ def run_one_chip(args, stats: CompileStats) -> None:
     # 2. convert
     cplan = plan_subnet_exec(cfg, purpose="convert")
     check(cplan.route == "kernel_infer", f"convert route {cplan.route}")
+    # layer 1's hidden function (a graph node's: its first branch's)
+    graph = cfg if is_graph_config(cfg) else cfg.graph()
+    fn1 = M.node_branch_params(graph.nodes[1], params["layers"][1],
+                               state["layers"][1])[0][0]
     n_kern = kernel_calls(jax.jit(
         lambda p, x: cplan.apply(p, x)).lower(
-            params["layers"][1]["fn"],
-            jnp.zeros((4096, cfg.layer_widths[1], cfg.fan_in))))
+            fn1, jnp.zeros((4096, cfg.layer_widths[1], cfg.layer_fan_in(1)))))
     check(n_kern == 1, f"conversion sweep lowers {n_kern} compiled kernels")
     t0 = time.perf_counter()
     tables, packed = TT.convert_packed(cfg, params, state, statics)
     convert_s = time.perf_counter() - t0
-    entries = int(sum(t.size for t in tables))
+    entries = int(sum(t.size for t in jax.tree.leaves(tables)))
     emit("convert", route=cplan.route, interpreted=not kernel_compiled(),
          compiled_kernels_per_chunk=n_kern, entries=entries,
          seconds=round(convert_s, 3), **stats.snapshot())
@@ -190,7 +211,7 @@ def run_one_chip(args, stats: CompileStats) -> None:
                                  train=False)
     codes = LI.input_codes(cfg, params, xe)
     lut_vals = LI.class_values(cfg, params,
-                               LI.lut_forward(cfg, tables, statics, codes))
+                               LI.lut_outputs(cfg, tables, statics, codes))
     differ = np.any(np.asarray(values) != np.asarray(lut_vals), axis=-1)
     emit("lut_vs_quantized", samples=len(xte),
          disagreeing=int(differ.sum()))
@@ -204,7 +225,8 @@ def run_one_chip(args, stats: CompileStats) -> None:
     reg.save(cfg.name, bundle_from_training(cfg, params, tables, statics,
                                             packed_tables=packed))
     bundle = reg.load(cfg.name, verify=True)
-    check(all((a == b).all() for a, b in zip(bundle.tables, tables)),
+    check(all((a == b).all() for a, b in zip(jax.tree.leaves(bundle.tables),
+                                             jax.tree.leaves(tables))),
           "loaded tables differ from the saved ones")
     emit("registry", path=str(REGISTRY), verified=True,
          packed_kib=bundle.num_packed_table_bytes / 1024)
@@ -268,8 +290,7 @@ def run_four_chips(args, stats: CompileStats) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from repro.config import get_config
-    from repro.core.nl_config import NeuraLUTConfig
+    from repro.core.nl_config import NeuraLUTConfig, is_graph_config
     from repro.core.train import train_neuralut_ensemble
     from repro.data import jsc_synthetic
     from repro.launch.mesh import make_sweep_mesh
@@ -278,7 +299,9 @@ def run_four_chips(args, stats: CompileStats) -> None:
     from repro.sweep import SweepPoint, run_pareto_sweep
 
     devices = jax.devices()[:4]
-    cfg = get_config(ARCH)
+    cfg = args.cfg
+    check(not is_graph_config(cfg), "the four-chip paths take a chain "
+                                    f"configuration, not {cfg.name}")
     bundle = _random_bundle(cfg, args.seed)
     xte, _ = jsc_synthetic(4000, seed=args.seed + 1)
     want = oracle_preds(cfg, bundle, xte)
@@ -355,8 +378,11 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the multi-device paths")
+    ap.add_argument("--arch", default=ARCH,
+                    help="a registered LUT configuration, chain or graph")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    args.cfg = lut_config(args.arch)
 
     from repro.launch.compile_cache import setup_compile_cache
     cache_dir = setup_compile_cache()
@@ -365,7 +391,7 @@ def main() -> None:
     devs = jax.devices()
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs)}
-    emit("device", **device, compile_cache=cache_dir)
+    emit("device", **device, arch=args.arch, compile_cache=cache_dir)
     check(device["platform"] == "tpu", f"no TPU: {device}")
     check(len(devs) >= args.chips, f"--chips {args.chips} needs "
                                    f"{args.chips} devices, have {len(devs)}")

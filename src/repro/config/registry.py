@@ -32,6 +32,7 @@ _CONFIG_MODULES = (
     "neuralut_jsc_5l",
     "polylut_add_jsc_2l",
     "polylut_add_jsc_5l",
+    "polylut_add_jsc_xl",
     "lm_100m",
 )
 

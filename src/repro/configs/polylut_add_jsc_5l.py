@@ -1,5 +1,7 @@
 """PolyLUT-Add JSC-5L — a deeper adder-tree LUT graph in the
-high-accuracy JSC segment (PolyLUT-Add, arXiv:2406.04910).
+high-accuracy JSC segment, built on the adder node of PolyLUT-Add
+(arXiv:2406.04910).  Its widths are this repository's test geometry, not
+a row of the paper's model table: ``polylut_add_jsc_xl`` is that row.
 
 Three stacked arity-2 adder nodes then an arity-1 classifier.  Inner
 nodes consume the previous node's 5-bit summed codes (F=3 -> 2^15-entry

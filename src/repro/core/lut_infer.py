@@ -176,9 +176,14 @@ def class_values(cfg, params: Params, out_codes: jax.Array
     return (out_codes.astype(jnp.float32) - 2 ** (cfg.beta - 1)) * s
 
 
+def lut_outputs(cfg, tables, statics, codes: jax.Array) -> jax.Array:
+    """The oracle for ``cfg``: :func:`graph_lut_forward` for a LUT graph,
+    :func:`lut_forward` for a chain."""
+    fwd = graph_lut_forward if is_graph_config(cfg) else lut_forward
+    return fwd(cfg, tables, statics, codes)
+
+
 def predict(cfg, params: Params, tables, statics,
             x: jax.Array) -> jax.Array:
-    codes = input_codes(cfg, params, x)
-    fwd = graph_lut_forward if is_graph_config(cfg) else lut_forward
-    out = fwd(cfg, tables, statics, codes)
+    out = lut_outputs(cfg, tables, statics, input_codes(cfg, params, x))
     return jnp.argmax(class_values(cfg, params, out), axis=-1)

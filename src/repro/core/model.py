@@ -173,10 +173,10 @@ def graph_init(cfg: LUTGraphConfig, key) -> Tuple[Params, Params]:
         # sqrt(A) more headroom so the per-branch codes start unsaturated.
         lp["quant"] = quant.quant_init(nd.width,
                                        2.0 * (nd.arity ** 0.5) / c)
-        bn0 = {"g": jnp.ones((nd.width,), jnp.float32),
-               "b": jnp.zeros((nd.width,), jnp.float32)}
-        lp["bn"] = bn0 if nd.arity == 1 else [
-            dict(bn0) for _ in range(nd.arity)]
+        # arrays of their own per branch: a training step donates every
+        # leaf, and a buffer that two leaves share cannot be donated
+        bns = [quant.bn_init(nd.width)[0] for _ in range(nd.arity)]
+        lp["bn"] = bns[0] if nd.arity == 1 else bns
     state = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), spec_s,
                          is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
     for i, nd in enumerate(cfg.nodes):

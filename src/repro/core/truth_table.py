@@ -302,14 +302,14 @@ def _graph_node_sweep(cfg: LUTGraphConfig, params: Params, state: Params,
     src_log_s = tuple(_source_log_s(params, b)
                       for b in cfg.node_sources(idx))
     tables, packeds = [], []
-    with TraceAnnotation(S.CONVERT_LAYER, layer=idx,
+    with TraceAnnotation(S.CONVERT_LAYER, layer=idx, arity=nd.arity,
                          entries=t * cfg.layer_widths[idx] * len(conns)):
-        for conn, (fnp, bnp, bns) in zip(conns,
-                                         node_branch_params(nd, lp, ls)):
-            with TraceAnnotation(S.CONVERT_SWEEP):
+        for a, (conn, (fnp, bnp, bns)) in enumerate(
+                zip(conns, node_branch_params(nd, lp, ls))):
+            with TraceAnnotation(S.CONVERT_SWEEP, branch=a):
                 table, packed = fn(src_log_s, conn, fnp, bnp, bns,
                                    lp["quant"])
-            with TraceAnnotation(S.CONVERT_FETCH):
+            with TraceAnnotation(S.CONVERT_FETCH, branch=a):
                 tables.append(np.asarray(table))
                 packeds.append(None if packed is None
                                else np.asarray(packed))

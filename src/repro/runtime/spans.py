@@ -30,10 +30,12 @@ Each span nests, on its own thread, inside the one listed as its parent:
 
   converter (``core/truth_table.py``)
     convert.layer   one layer's (or graph node's) sweep; ``layer``,
-                    ``entries``
+                    ``entries``, and a graph node's ``arity``
     convert.sweep   the jitted sweep's dispatch (the sweep computes the
-                    slot scales on the device)
-    convert.fetch   the tables' copy back to the host
+                    slot scales on the device); in a graph node, one per
+                    branch, with its ``branch``
+    convert.fetch   the tables' copy back to the host; in a graph node,
+                    one per branch, with its ``branch``
 
 The jitted programs carry ``jax.named_scope``s, so that their device
 operations name the layer in their metadata: :data:`SCOPE_SERVE_STEP`,

@@ -363,7 +363,24 @@ def test_make_forward_fn_plan_equals_keywords():
 
 
 @pytest.mark.parametrize("arch", ["polylut-add-jsc-2l",
-                                  "polylut-add-jsc-5l"])
+                                  "polylut-add-jsc-5l",
+                                  "polylut-add-jsc-xl"])
+def test_graph_init_gives_every_leaf_its_own_buffer(arch):
+    """The training epoch donates every leaf of (params, state, opt) on an
+    accelerator, and a buffer that two leaves share cannot be donated
+    twice: each adder branch's batch norm needs arrays of its own."""
+    from repro.config import get_config
+    from repro.optim import adamw_init
+    cfg = get_config(arch, reduced=True)
+    params, state = M.model_init(cfg, jax.random.PRNGKey(0))
+    leaves = jax.tree.leaves((params, state, adamw_init(params)))
+    ptrs = [leaf.unsafe_buffer_pointer() for leaf in leaves]
+    assert len(set(ptrs)) == len(ptrs)
+
+
+@pytest.mark.parametrize("arch", ["polylut-add-jsc-2l",
+                                  "polylut-add-jsc-5l",
+                                  "polylut-add-jsc-xl"])
 def test_polylut_add_end_to_end_bit_exact(arch, tmp_path):
     from repro.config import get_config
     from repro.core.train import train_neuralut

@@ -157,6 +157,17 @@ def test_converter_spans_nest_per_layer(cfg, tmp_path):
                      for tb in tables]
 
 
+def test_graph_converter_spans_name_the_branch(tmp_path):
+    """A graph node's layer span carries its arity, and each of its
+    sweeps and fetches the branch it converts, in branch order."""
+    spans, _ = _converted_spans(GRAPH, tmp_path)
+    layers = [a for n, _, a in spans if n == S.CONVERT_LAYER]
+    assert [a["arity"] for a in layers] == [nd.arity for nd in GRAPH.nodes]
+    for name in (S.CONVERT_SWEEP, S.CONVERT_FETCH):
+        assert [a["branch"] for n, _, a in spans if n == name] == [
+            b for nd in GRAPH.nodes for b in range(nd.arity)]
+
+
 def test_recording_follows_the_profiler(tmp_path):
     assert not S.recording()
     with jax.profiler.trace(str(tmp_path)):

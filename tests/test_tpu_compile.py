@@ -8,8 +8,10 @@ tests run the compiler that ships with libtpu against a ``v5e:2x2``
 topology description at the real widths of every benchmark geometry:
 the serving cascade (``lut_cascade``), the conversion kernel
 (``grouped_subnet``) and the training kernel (``subnet_train_op``,
-forward and ``jax.grad``).  Nothing executes, so they say nothing about
-results or speed.
+forward and ``jax.grad``) at every layer (a LUT graph: node) shape, and
+the whole conversion sweep of each distinct layer or node geometry (its
+source bits, its table of up to 2^15 entries walked in chunks).  Nothing
+executes, so they say nothing about results or speed.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load libtpu, and every test worker imports
@@ -25,6 +27,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import subnet
+from repro.core import truth_table as TT
+from repro.core.exec_plan import SubnetExec
 from repro.core.lut_infer import packed_slots
 from repro.core.nl_config import is_graph_config
 from repro.kernels.lut_cascade import (cascade_meta, graph_cascade_meta,
@@ -33,8 +37,9 @@ from repro.kernels.neuralut_mlp import grouped_subnet
 from repro.kernels.ops import subnet_params_to_kernel, subnet_train_apply
 
 GEOMETRIES = ["neuralut_jsc_2l", "neuralut_jsc_5l", "neuralut_hdr_5l",
-              "polylut_add_jsc_5l"]
-SUBNET_GEOMETRIES = ["neuralut_jsc_5l", "neuralut_hdr_5l"]
+              "polylut_add_jsc_5l", "polylut_add_jsc_xl"]
+SUBNET_GEOMETRIES = ["neuralut_jsc_5l", "neuralut_hdr_5l",
+                     "polylut_add_jsc_5l", "polylut_add_jsc_xl"]
 SERVE_BATCH = 256   # the serving engine's largest bucket
 TRAIN_BATCH = 256   # core.train.train_neuralut's default batch
 CONVERT_CHUNK = 4096  # truth_table's default sweep chunk
@@ -44,15 +49,32 @@ def _cfg(mod):
     return importlib.import_module(f"repro.configs.{mod}").full()
 
 
-def _subnet_layers(mod):
-    """(O, F) of every distinct sub-network layer shape in a config."""
+def _sweeps(mod):
+    """(source bits, F, table size, O, source pool) of every distinct
+    conversion sweep of a config: one per chain layer, one per graph node
+    (its branches share it).  The pool is the width of each source buffer
+    the node reads (a chain layer reads the buffer before it), as the
+    converter hands the sweep one quantizer scale per source; a graph
+    node's source bits are beta + log2 of the arity of the node it
+    reads."""
     cfg = _cfg(mod)
-    return sorted({(cfg.layer_widths[i], cfg.layer_fan_in(i))
-                   for i in range(cfg.num_layers)}, reverse=True)
+    g = cfg if is_graph_config(cfg) else cfg.graph()
+    return sorted({(g.layer_in_bits(i), g.layer_fan_in(i), g.table_size(i),
+                    g.layer_widths[i],
+                    tuple(g.buffer_width(b) for b in g.node_sources(i)))
+                   for i in range(g.num_layers)}, reverse=True)
+
+
+def _subnet_layers(mod):
+    """(O, F) of every distinct sub-network layer (graph node) shape."""
+    return sorted({(o, f) for _, f, _, o, _ in _sweeps(mod)}, reverse=True)
 
 
 SUBNET_CASES = [(mod, o, f) for mod in SUBNET_GEOMETRIES
                 for o, f in _subnet_layers(mod)]
+SWEEP_CASES = [pytest.param(mod, *g, id="-".join(
+                   map(str, (mod, *g[:4], "+".join(map(str, g[4]))))))
+               for mod in SUBNET_GEOMETRIES for g in _sweeps(mod)]
 
 
 @pytest.fixture(scope="module")
@@ -151,3 +173,22 @@ def test_subnet_train_op_compiles(one_chip, mod, o, f):
     _compile(fwd, p, xg, kernels=("subnet_train_fwd",))
     _compile(jax.grad(lambda p, x: jnp.sum(fwd(p, x)), argnums=(0, 1)),
              p, xg, kernels=("subnet_train_fwd", "subnet_train_bwd"))
+
+
+@pytest.mark.parametrize("mod,bits,f,t,o,pool", SWEEP_CASES)
+def test_convert_sweep_compiles(one_chip, mod, bits, f, t, o, pool):
+    """The jitted sweep as the converter builds it on the chip (route
+    ``kernel_infer``): codes of ``bits`` bits enumerated on the device,
+    ``t`` entries in chunks of the default batch, packed, over the
+    quantizer scales of the node's own source buffers."""
+    cfg = _cfg(mod)
+    chunk = TT._chunk_for(t, CONVERT_CHUNK)
+    plan = SubnetExec(kind="subnet", route="kernel_infer", skip=cfg.skip,
+                      interpret=False)
+    sweep = TT._make_sweep(plan, bits, cfg.beta, f, t, chunk, True)
+    vec = _sds(one_chip, (o,))
+    _compile(sweep, tuple(_sds(one_chip, (w,)) for w in pool),
+             _sds(one_chip, (o, f), jnp.int32),
+             _subnet_params(cfg, o, f, one_chip), {"g": vec, "b": vec},
+             {"mean": vec, "var": vec}, {"log_s": vec},
+             kernels=("subnet_infer",))
