@@ -21,11 +21,15 @@ the same shape share one executable and converting a second model of
 the same family costs zero recompiles — the per-layer ``@jax.jit`` of
 the old converter is gone.  The sweep also computes each slot's scale
 (``exp`` of the source quantizers' ``log_s``, gathered by the
-connectivity), so a layer is one dispatch and one fetch with no eager
-op before it.  The number of source channels enters only through those
-operands' shapes, so jit compiles once per distinct (source channels,
-layer width) under a static key (hdr-5l: four sweep compiles in all).  ``convert_cache_stats``
-exposes compile counts for tests and profiling.
+connectivity), so a layer is one dispatch with no eager op before it.
+The number of source channels enters only through those operands'
+shapes, so jit compiles once per distinct (source channels, layer
+width) under a static key (hdr-5l: four sweep compiles in all).
+``convert_cache_stats`` exposes compile counts for tests and profiling.
+
+No sweep reads another's output, so a conversion dispatches every
+layer's sweep first and then brings all the tables back to the host in
+one wait (``_fetch``): one device-to-host round trip, not one per array.
 
 The hidden function runs through a ``core.exec_plan.SubnetExec``: the
 convert-purpose planner default is the canonical jnp einsum off-TPU
@@ -189,13 +193,14 @@ def _guard_size(cfg: NeuraLUTConfig, layer_idx: int) -> None:
 def _layer_sweep(cfg: NeuraLUTConfig, params: Params, state: Params,
                  statics: List[Dict], layer_idx: int, *, batch: int,
                  exec_plan: SubnetExec
-                 ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
-    """One layer's fused sweep -> ((O, T) uint16, packed int32 | None).
+                 ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """Dispatch one layer's fused sweep -> ((O, T) uint16, packed int32
+    | None), left on the device for :func:`_fetch`.
 
     The sweep gets the source quantizer's ``log_s`` (the input's for
     layer 0, the previous layer's otherwise) and the layer's (O, F)
     connectivity as they are: it computes the slot scales itself, so
-    its dispatch is the layer's only host work before the fetch."""
+    its dispatch is the layer's only host work."""
     _guard_size(cfg, layer_idx)
     t = cfg.table_size(layer_idx)
     chunk = _chunk_for(t, batch)
@@ -204,13 +209,19 @@ def _layer_sweep(cfg: NeuraLUTConfig, params: Params, state: Params,
     with TraceAnnotation(S.CONVERT_LAYER, layer=layer_idx,
                          entries=t * cfg.layer_widths[layer_idx]):
         with TraceAnnotation(S.CONVERT_SWEEP):
-            table, packed = fn((_source_log_s(params, layer_idx),),
-                               statics[layer_idx]["conn"], lp["fn"],
-                               lp["bn"], state["layers"][layer_idx]["bn"],
-                               lp["quant"])
-        with TraceAnnotation(S.CONVERT_FETCH):
-            return (np.asarray(table),
-                    None if packed is None else np.asarray(packed))
+            return fn((_source_log_s(params, layer_idx),),
+                      statics[layer_idx]["conn"], lp["fn"], lp["bn"],
+                      state["layers"][layer_idx]["bn"], lp["quant"])
+
+
+def _fetch(arrays):
+    """Bring a conversion's device arrays (any nesting of lists) back to
+    the host as numpy arrays of the same structure, in one wait:
+    ``jax.device_get`` starts every copy before it waits on any."""
+    leaves = jax.tree_util.tree_leaves(arrays)
+    with TraceAnnotation(S.CONVERT_FETCH, arrays=len(leaves),
+                         bytes=sum(a.nbytes for a in leaves)):
+        return jax.device_get(arrays)
 
 
 def _convert_plan(cfg: NeuraLUTConfig,
@@ -233,7 +244,7 @@ def layer_truth_table(cfg: NeuraLUTConfig, params: Params, state: Params,
                             batch=batch,
                             exec_plan=_convert_plan(cfg,
                                                     use_subnet_kernel))
-    return table.astype(np.uint16)
+    return _fetch(table)
 
 
 def convert(cfg, params: Params, state: Params,
@@ -244,9 +255,10 @@ def convert(cfg, params: Params, state: Params,
     if is_graph_config(cfg):
         return convert_graph(cfg, params, state, statics, batch=batch,
                              use_subnet_kernel=use_subnet_kernel)
-    return [layer_truth_table(cfg, params, state, statics, i, batch=batch,
-                              use_subnet_kernel=use_subnet_kernel)
-            for i in range(cfg.num_layers)]
+    exec_plan = _convert_plan(cfg, use_subnet_kernel)
+    return _fetch([_layer_sweep(cfg, params, state, statics, i,
+                                batch=batch, exec_plan=exec_plan)[0]
+                   for i in range(cfg.num_layers)])
 
 
 def convert_packed(cfg, params: Params, state: Params,
@@ -276,7 +288,7 @@ def convert_packed(cfg, params: Params, state: Params,
                 f"(beta={cfg.beta}); geometry not servable bit-packed")
         tables.append(table)
         packeds.append(packed)
-    return tables, packeds
+    return _fetch((tables, packeds))
 
 
 # ---------------------------------------------------------------------------
@@ -286,11 +298,12 @@ def convert_packed(cfg, params: Params, state: Params,
 def _graph_node_sweep(cfg: LUTGraphConfig, params: Params, state: Params,
                       statics: List[Dict], idx: int, *, batch: int,
                       exec_plan: SubnetExec):
-    """One node's fused sweeps -> (per-branch [(O, T) uint16],
-    per-branch [packed int32 | None]).  Reuses the chain sweep cache:
-    the node's geometry key (beta_in, F, T) is all ``_get_sweep`` needs,
-    and every branch of a node shares one compiled executable, fed the
-    node's source log-scales and the branch's own connectivity."""
+    """Dispatch one node's fused sweeps -> (per-branch [(O, T) uint16],
+    per-branch [packed int32 | None]), left on the device for
+    :func:`_fetch`.  Reuses the chain sweep cache: the node's geometry
+    key (beta_in, F, T) is all ``_get_sweep`` needs, and every branch of
+    a node shares one compiled executable, fed the node's source
+    log-scales and the branch's own connectivity."""
     from repro.core.model import node_branch_params, node_static_conns
     _guard_size(cfg, idx)
     nd = cfg.nodes[idx]
@@ -309,10 +322,8 @@ def _graph_node_sweep(cfg: LUTGraphConfig, params: Params, state: Params,
             with TraceAnnotation(S.CONVERT_SWEEP, branch=a):
                 table, packed = fn(src_log_s, conn, fnp, bnp, bns,
                                    lp["quant"])
-            with TraceAnnotation(S.CONVERT_FETCH, branch=a):
-                tables.append(np.asarray(table))
-                packeds.append(None if packed is None
-                               else np.asarray(packed))
+            tables.append(table)
+            packeds.append(packed)
     return tables, packeds
 
 
@@ -323,12 +334,9 @@ def convert_graph(cfg: LUTGraphConfig, params: Params, state: Params,
     """Per-node truth tables: ``out[i]`` is node i's per-branch list of
     (O, T) uint16 tables."""
     exec_plan = _convert_plan(cfg, use_subnet_kernel)
-    out = []
-    for i in range(cfg.num_layers):
-        tables, _ = _graph_node_sweep(cfg, params, state, statics, i,
-                                      batch=batch, exec_plan=exec_plan)
-        out.append([t.astype(np.uint16) for t in tables])
-    return out
+    return _fetch([_graph_node_sweep(cfg, params, state, statics, i,
+                                     batch=batch, exec_plan=exec_plan)[0]
+                   for i in range(cfg.num_layers)])
 
 
 def convert_graph_packed(cfg: LUTGraphConfig, params: Params, state: Params,
@@ -351,4 +359,4 @@ def convert_graph_packed(cfg: LUTGraphConfig, params: Params, state: Params,
                 f"(beta={cfg.beta}); geometry not servable bit-packed")
         all_tables.append(tables)
         all_packed.append(packeds)
-    return all_tables, all_packed
+    return _fetch((all_tables, all_packed))

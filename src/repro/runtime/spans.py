@@ -29,13 +29,15 @@ Each span nests, on its own thread, inside the one listed as its parent:
     serve.resolve   setting the futures (clients' callbacks run here)
 
   converter (``core/truth_table.py``)
-    convert.layer   one layer's (or graph node's) sweep; ``layer``,
-                    ``entries``, and a graph node's ``arity``
+    convert.layer   one layer's (or graph node's) sweep dispatches;
+                    ``layer``, ``entries``, and a graph node's ``arity``
     convert.sweep   the jitted sweep's dispatch (the sweep computes the
                     slot scales on the device); in a graph node, one per
                     branch, with its ``branch``
-    convert.fetch   the tables' copy back to the host; in a graph node,
-                    one per branch, with its ``branch``
+    convert.fetch   one per conversion, after its last sweep: waiting for
+                    the device and copying every table back to the host
+                    at once; ``arrays`` (device arrays brought back) and
+                    ``bytes``
 
 The jitted programs carry ``jax.named_scope``s, so that their device
 operations name the layer in their metadata: :data:`SCOPE_SERVE_STEP`,
@@ -71,7 +73,7 @@ PARENT: Dict[str, Optional[str]] = {
     SERVE_RESOLVE: SERVE_BATCH,
     CONVERT_LAYER: None,
     CONVERT_SWEEP: CONVERT_LAYER,
-    CONVERT_FETCH: CONVERT_LAYER,
+    CONVERT_FETCH: None,
 }
 
 SCOPE_SERVE_STEP = "serve_step"

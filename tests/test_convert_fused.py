@@ -143,9 +143,13 @@ def test_jit_cache_size_version_safe():
 
 
 # ---------------------------------------------------------------------------
-# a warm conversion is one dispatch and one fetch per sweep: the slot
+# a warm conversion is one dispatch per sweep and one fetch: the slot
 # scales are computed inside the jitted sweep, never by eager ops
 
+
+CHAIN = NeuraLUTConfig(name="tt-chain", in_features=6,
+                       layer_widths=(8, 5, 3), num_classes=3, beta=2,
+                       fan_in=2, kind="subnet", depth=2, width=4, skip=0)
 
 # node c concatenates two sources, so its sweep gathers from a pool of
 # two quantizers' scales
@@ -159,11 +163,8 @@ MULTI_SOURCE_DAG = LUTGraphConfig(
            LUTNodeSpec(name="c", width=3, fan_in=2, inputs=("a", "b"))))
 
 
-@pytest.mark.parametrize("cfg", [
-    NeuraLUTConfig(name="tt-eager", in_features=6, layer_widths=(8, 5, 3),
-                   num_classes=3, beta=2, fan_in=2, kind="subnet",
-                   depth=2, width=4, skip=0),
-    MULTI_SOURCE_DAG], ids=["chain", "dag"])
+@pytest.mark.parametrize("cfg", [CHAIN, MULTI_SOURCE_DAG],
+                         ids=["chain", "dag"])
 def test_warm_conversion_binds_no_eager_primitive(cfg, monkeypatch):
     from jax._src import dispatch
     if not (hasattr(dispatch, "apply_primitive")
@@ -272,3 +273,70 @@ def test_chunked_sweep_equals_single_chunk():
     small = TT.layer_truth_table(cfg, params, state, statics, 0, batch=24)
     whole = TT.layer_truth_table(cfg, params, state, statics, 0, batch=64)
     assert (small == whole).all()
+
+
+# ---------------------------------------------------------------------------
+# one fetch per conversion: every entry point returns host arrays of the
+# documented form, and the packed form's tables equal the unpacked one's
+
+
+def _per_branch(cfg, tables):
+    """Each layer's tables as a list, whatever the config's kind."""
+    return [tb if isinstance(cfg, LUTGraphConfig) else [tb]
+            for tb in tables]
+
+
+@pytest.mark.parametrize("cfg", [CHAIN, MULTI_SOURCE_DAG],
+                         ids=["chain", "dag"])
+def test_convert_and_convert_packed_tables_identical(cfg):
+    statics, params, state = _trained_like(cfg)
+    unpacked = TT.convert(cfg, params, state, statics)
+    tables, packed = TT.convert_packed(cfg, params, state, statics)
+    assert len(unpacked) == len(tables) == len(packed) == cfg.num_layers
+    for i, (u, t, p) in enumerate(zip(*(_per_branch(cfg, x) for x in
+                                        (unpacked, tables, packed)))):
+        assert len(u) == len(t) == len(p), f"layer {i}"
+        for a, b, w in zip(u, t, p):
+            assert a.dtype == b.dtype == np.uint16
+            assert w.dtype == np.int32
+            np.testing.assert_array_equal(a, b)
+            np.testing.assert_array_equal(LI.pack_tables(b, cfg.beta), w)
+
+
+@pytest.mark.parametrize("cfg", [CHAIN, MULTI_SOURCE_DAG],
+                         ids=["chain", "dag"])
+def test_convert_returns_uint16_host_tables_of_documented_shapes(cfg):
+    statics, params, state = _trained_like(cfg)
+    tables = _per_branch(cfg, TT.convert(cfg, params, state, statics))
+    arity = ([nd.arity for nd in cfg.nodes]
+             if isinstance(cfg, LUTGraphConfig) else [1] * cfg.num_layers)
+    assert [len(tb) for tb in tables] == arity
+    for i, tb in enumerate(tables):
+        for t in tb:
+            assert type(t) is np.ndarray and t.dtype == np.uint16
+            assert t.shape == (cfg.layer_widths[i], cfg.table_size(i))
+            assert int(t.max()) < 2 ** cfg.beta
+
+
+@pytest.mark.parametrize("cfg,where", [
+    # layer 0 (T = 2^(2*2) = 16) fills one word of P = 16 slots;
+    # layer 1 (T = 2^(2*1) = 4) cannot
+    (NeuraLUTConfig(name="tt-late-small", in_features=4,
+                    layer_widths=(4, 2), num_classes=2, beta=2,
+                    fan_in=1, fan_in_0=2, kind="linear"), "layer 1"),
+    (LUTGraphConfig(name="tt-dag-small", in_features=4, num_classes=2,
+                    beta=2, kind="linear",
+                    nodes=(LUTNodeSpec(name="a", width=4, fan_in=2,
+                                       inputs=(INPUT,)),
+                           LUTNodeSpec(name="c", width=2, fan_in=1,
+                                       inputs=("a",)))), "node 1")],
+    ids=["chain", "dag"])
+def test_unpackable_geometry_names_its_layer_before_any_fetch(
+        cfg, where, monkeypatch):
+    statics, params, state = _trained_like(cfg)
+    fetched = []
+    monkeypatch.setattr(TT, "_fetch", fetched.append)
+    with pytest.raises(ValueError, match=f"^{where}: .*packed word "
+                                         f"capacity"):
+        TT.convert_packed(cfg, params, state, statics)
+    assert fetched == []

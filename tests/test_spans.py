@@ -117,8 +117,8 @@ def _converted_spans(cfg, tmp_path, seed=0):
     params, state = M.model_init(cfg, jax.random.PRNGKey(seed))
     TT.convert_packed(cfg, params, state, statics)  # compiles
     with jax.profiler.trace(str(tmp_path)):
-        tables, _ = TT.convert_packed(cfg, params, state, statics)
-    return _spans(tmp_path), tables
+        converted = TT.convert_packed(cfg, params, state, statics)
+    return _spans(tmp_path), converted
 
 
 GRAPH = LUTGraphConfig(
@@ -131,7 +131,8 @@ GRAPH = LUTGraphConfig(
 @pytest.mark.parametrize("cfg", [_tiny_cfg(), GRAPH],
                          ids=["chain", "graph"])
 def test_converter_spans_nest_per_layer(cfg, tmp_path):
-    spans, tables = _converted_spans(cfg, tmp_path)
+    spans, converted = _converted_spans(cfg, tmp_path)
+    tables = converted[0]
     assert {n for n, _, _ in spans} == {
         n for n in S.PARENT if n.startswith("convert.")}
     for name, parent, _ in spans:
@@ -141,31 +142,37 @@ def test_converter_spans_nest_per_layer(cfg, tmp_path):
     sizes = [sum(np.asarray(t).size for t in
                  (tb if isinstance(tb, list) else [tb])) for tb in tables]
     assert [a["entries"] for a in layers] == sizes
-    fetches = sum(1 for n, _, _ in spans if n == S.CONVERT_FETCH)
-    assert fetches == sum(len(tb) if isinstance(tb, list) else 1
-                          for tb in tables)
-    # each layer holds one sweep and one fetch per branch, and nothing
-    # else: no host work between the layer's start and its dispatch
+    # one fetch per conversion, opened after the last sweep, bringing
+    # back every table and packed array the conversion returns
+    fetches = [a for n, _, a in spans if n == S.CONVERT_FETCH]
+    assert len(fetches) == 1 and spans[-1][0] == S.CONVERT_FETCH
+    leaves = jax.tree_util.tree_leaves(converted)
+    assert fetches[0]["arrays"] == len(leaves)
+    assert fetches[0]["bytes"] == sum(a.nbytes for a in leaves)
+    # each layer holds one sweep per branch and nothing else: no host
+    # work between the layer's start and its dispatch, and no wait
     inner = []
     for name, parent, _ in spans:
         if name == S.CONVERT_LAYER:
             inner.append([])
         elif parent == S.CONVERT_LAYER:
             inner[-1].append(name)
-    assert inner == [[S.CONVERT_SWEEP, S.CONVERT_FETCH] *
+    assert inner == [[S.CONVERT_SWEEP] *
                      (len(tb) if isinstance(tb, list) else 1)
                      for tb in tables]
 
 
 def test_graph_converter_spans_name_the_branch(tmp_path):
     """A graph node's layer span carries its arity, and each of its
-    sweeps and fetches the branch it converts, in branch order."""
+    sweeps the branch it converts, in branch order; the conversion's one
+    fetch names no branch."""
     spans, _ = _converted_spans(GRAPH, tmp_path)
     layers = [a for n, _, a in spans if n == S.CONVERT_LAYER]
     assert [a["arity"] for a in layers] == [nd.arity for nd in GRAPH.nodes]
-    for name in (S.CONVERT_SWEEP, S.CONVERT_FETCH):
-        assert [a["branch"] for n, _, a in spans if n == name] == [
-            b for nd in GRAPH.nodes for b in range(nd.arity)]
+    assert [a["branch"] for n, _, a in spans if n == S.CONVERT_SWEEP] == [
+        b for nd in GRAPH.nodes for b in range(nd.arity)]
+    assert all("branch" not in a
+               for n, _, a in spans if n == S.CONVERT_FETCH)
 
 
 def test_recording_follows_the_profiler(tmp_path):
